@@ -197,16 +197,35 @@ def drive(
     so a guarded solve never compiles the same program twice); sizes it
     covers are skipped in warmup. ``precompile_s`` is the wall time the
     caller already spent producing them — folded into ``compile_s`` and
-    ``total_s`` so guard minutes never vanish from the reported timing."""
-    t_all0 = time.perf_counter()
-    chunk = event_interval(cfg)
-    remaining = cfg.ntime - start_step
+    ``total_s`` so guard minutes never vanish from the reported timing.
+
+    The solve is one ``heat.drive.solve`` span (runtime/trace.py) around
+    its compile, chunk dispatches, final fence and fetch; with the
+    profiler on they sit beside the device's operations."""
     # request-scoped tracing (runtime/trace.py): the solo path records
     # into the process-global ring so `heat-tpu run --trace` puts chunk
     # dispatches, checkpoint snapshots, and the background writer's
     # D2H+publish spans (the PR-1 overlap) on one Perfetto timeline.
     tracer = trace_mod.get_tracer()
     drv_track = tracer.thread_track("solve") if tracer.enabled else None
+    with tracer.span("heat.drive.solve", drv_track, cat="solve",
+                     args={"steps": cfg.ntime - start_step, "n": cfg.n,
+                           "backend": cfg.backend}):
+        return _drive(cfg, T_dev, advance, tracer, drv_track, start_step,
+                      to_host, warmup, fetch, warm_exec, two_point_repeats,
+                      precompiled, precompile_s)
+
+
+def _drive(cfg, T_dev, advance, tracer, drv_track, start_step, to_host,
+           warmup, fetch, warm_exec, two_point_repeats, precompiled,
+           precompile_s) -> SolveResult:
+    t_all0 = time.perf_counter()
+    chunk = event_interval(cfg)
+    remaining = cfg.ntime - start_step
+    # computed-against-useful point-steps (runtime/trace.py COUNTERS): the
+    # backend reports what its kernels compute for a k-step chunk, if it can
+    points_computed = getattr(advance, "points_computed", None)
+    computed_by_k: dict = {}
 
     # AOT-compile every chunk size the loop will encounter (at most two: the
     # steady chunk and a final remainder) so no compile lands inside the
@@ -215,13 +234,12 @@ def drive(
     compile_s = precompile_s
     compiled = dict(precompiled or {})
     if warmup and remaining > 0:
-        t_c0 = time.perf_counter()
-        compiled, spent = aot_compile_chunks(
-            advance, T_dev, chunk_sizes(cfg, remaining), compiled,
-            label=f"solve {cfg.backend} n{cfg.n}^{cfg.ndim} {cfg.dtype}")
-        if tracer.enabled and spent > 0:
-            tracer.complete("compile", drv_track, t_c0, cat="solve",
-                            args={"sizes": chunk_sizes(cfg, remaining)})
+        sizes = chunk_sizes(cfg, remaining)
+        with tracer.span("heat.drive.compile", drv_track, cat="solve",
+                         args={"sizes": sizes}):
+            compiled, spent = aot_compile_chunks(
+                advance, T_dev, sizes, compiled,
+                label=f"solve {cfg.backend} n{cfg.n}^{cfg.ndim} {cfg.dtype}")
         compile_s += spent
         t0 = time.perf_counter()
         if warm_exec:
@@ -337,14 +355,20 @@ def drive(
                 while step < cfg.ntime:
                     k = min(chunk, cfg.ntime - step)
                     fn = compiled.get(k)
-                    t_ch = time.perf_counter() if tracer.enabled else 0.0
-                    T_dev = fn(T_dev) if fn is not None else advance(T_dev, k)
+                    # dispatch-side span: the enqueue cost, not the device
+                    # time (the loop deliberately never fences)
+                    with tracer.span("heat.drive.dispatch", drv_track,
+                                     cat="solve", args={"k": k}):
+                        T_dev = (fn(T_dev) if fn is not None
+                                 else advance(T_dev, k))
                     step += k
-                    if tracer.enabled:
-                        # dispatch-side span: the enqueue cost, not the
-                        # device time (the loop deliberately never fences)
-                        tracer.complete(f"chunk @{step}", drv_track, t_ch,
-                                        cat="solve", args={"k": k})
+                    tracer.count("heat.drive.points_useful", cfg.points * k)
+                    if points_computed is not None:
+                        if k not in computed_by_k:
+                            computed_by_k[k] = points_computed(k)
+                        if computed_by_k[k] is not None:
+                            tracer.count("heat.kernel.points_computed",
+                                         computed_by_k[k])
                     if plan is not None:
                         plan.maybe_crash(step)
                         T_dev = plan.maybe_nan(step, T_dev)
@@ -390,11 +414,8 @@ def drive(
                 if pending_flag is None or not _settle_pending():
                     break
                 # final boundary flagged and rolled back: resume stepping
-            t_sync = time.perf_counter() if tracer.enabled else 0.0
-            sync(T_dev)
-            if tracer.enabled:
-                tracer.complete("final-sync", drv_track, t_sync,
-                                cat="solve")
+            with tracer.span("heat.drive.sync", drv_track, cat="solve"):
+                sync(T_dev)
     except BaseException:
         # drain-on-exception: every queued snapshot still lands on disk (a
         # blow-up's last good boundary is exactly the state a resume
@@ -403,10 +424,6 @@ def drive(
             writer.drain(raise_errors=False)
         raise
     solve_s = time.perf_counter() - t0
-    if tracer.enabled:
-        tracer.complete("solve", drv_track, t0, t0 + solve_s, cat="solve",
-                        args={"steps": remaining, "n": cfg.n,
-                              "backend": cfg.backend})
     if writer is not None:
         # post-solve flush, deliberately OUTSIDE solve_s: the device has
         # finished stepping, so the remaining writes overlap nothing —
@@ -429,7 +446,10 @@ def drive(
 
     # fetch=False skips the final device->host copy (benchmark mode: the
     # caller only wants timings)
-    T_host = to_host(T_dev) if fetch else None
+    T_host = None
+    if fetch:
+        with tracer.span("heat.drive.fetch", drv_track, cat="solve"):
+            T_host = to_host(T_dev)
     gsum = gsum_dtype = None
     if cfg.report_sum:
         # The intended-but-commented-out global reduction of the reference

@@ -24,6 +24,7 @@ from ..ops.pallas_stencil import (
     ftcs_step_edges_pallas,
     ftcs_step_ghost_pallas,
     ftcs_step_periodic_pallas,
+    multistep_points_bc,
 )
 from ..ops.stencil import run_steps
 from . import SolveResult, register
@@ -65,7 +66,25 @@ def make_advance(cfg: HeatConfig):
             T = jax.lax.fori_loop(0, n_fused, lambda i, t: multi(t, kf), T)
         return run_steps(T, rem if kf > 1 else k, step)
 
+    # drive() counts the kernels' computed point-steps per chunk from this
+    advance.points_computed = functools.partial(kernel_points, cfg)
     return advance
+
+
+def kernel_points(cfg: HeatConfig, k: int) -> Optional[int]:
+    """Point-steps the Pallas kernels compute for ``advance(T, k)``: the
+    fused passes plus the single-step remainder, each counted by the
+    kernels' own plan. None where XLA does the stepping (float64)."""
+    kf = fuse_depth(cfg)
+    n_fused, rem = divmod(k, kf) if kf > 1 else (0, k)
+    total = 0
+    for depth, times in ((kf, n_fused), (1, rem)):
+        if times:
+            p = multistep_points_bc(cfg.shape, cfg.dtype, cfg.bc, depth)
+            if p is None:
+                return None
+            total += times * p
+    return total
 
 
 @register("pallas")

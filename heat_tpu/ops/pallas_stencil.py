@@ -172,6 +172,23 @@ def effective_chunk_2d(shape, dtype_str, ksteps: int | None = None) -> int:
     return plan[1] if plan[0] == "thin" else plan[-1]
 
 
+def _pass_depths(ksteps: int, cap: int) -> list:
+    """Fusion depth of each kernel pass when ``ksteps`` steps run in passes
+    of at most ``cap``: the one chunking every multistep loop here uses."""
+    full, rem = divmod(ksteps, cap)
+    return [cap] * full + ([rem] if rem else [])
+
+
+def _thin_geometry(m: int, n: int, ksteps: int, dtype):
+    """(n_pad, kpad, tile, m_pad) of one thin-band pass of ``ksteps`` steps
+    over an (m, n) array: the one derivation ``_pallas_2d`` and
+    ``multistep_points`` share."""
+    n_pad = _round_up(max(n, 128), 128)
+    kpad = _halo_2d(ksteps, dtype)
+    tile = _tile_2d(n_pad, kpad)
+    return n_pad, kpad, tile, _round_up(max(m, tile), tile)
+
+
 def _tile_2d(n_pad: int, kpad: int) -> int:
     """Row-tile height: a multiple of kpad (so halo blocks index evenly),
     sized to keep the (tile + 2*kpad)-row band near the budget (the band is
@@ -265,11 +282,8 @@ def _pallas_2d(T: jax.Array, r: float, ksteps: int,
         # halo blocks of the first/last grid step is only read by frozen
         # rows; custom bounds callers own a discard margin >= ksteps instead
     bounds = bounds.reshape(1, 4).astype(jnp.int32)
-    n_pad = _round_up(max(n, 128), 128)
-    kpad = _halo_2d(ksteps, T.dtype)
-    tile = _tile_2d(n_pad, kpad)
+    n_pad, kpad, tile, m_pad = _thin_geometry(m, n, ksteps, T.dtype)
     assert ksteps <= kpad <= tile and tile % kpad == 0
-    m_pad = _round_up(max(m, tile), tile)
     padded = (m_pad != m) or (n_pad != n)
     Tp = jnp.pad(T, ((0, m_pad - m), (0, n_pad - n))) if padded else T
     grid = (m_pad // tile,)
@@ -282,6 +296,7 @@ def _pallas_2d(T: jax.Array, r: float, ksteps: int,
         _make_kernel_2d(float(r), tile, kpad, n_pad, ksteps),
         out_shape=jax.ShapeDtypeStruct(Tp.shape, Tp.dtype),
         grid=grid,
+        name="heat_ftcs_2d",
         in_specs=[
             smem,
             halo(lambda i: (jnp.maximum(i * ratio - 1, 0), 0)),
@@ -508,6 +523,7 @@ def _pallas_3d_aligned(Tp: jax.Array, r: float, ksteps: int, kplan: int,
         _make_kernel_3d(float(r), R, M, kplan, km, n_pad, ksteps),
         out_shape=jax.ShapeDtypeStruct(Tp.shape, Tp.dtype),
         grid=grid,
+        name="heat_ftcs_3d",
         in_specs=[smem] + in_specs,
         out_specs=out_spec,
         compiler_params=pltpu.CompilerParams(
@@ -646,6 +662,7 @@ def _pallas_2d_coltiled(Tp: jax.Array, r: float, ksteps: int, R: int, C: int,
         _make_kernel_2d_coltiled(float(r), R, C, kr, kc, ksteps),
         out_shape=jax.ShapeDtypeStruct(Tp.shape, Tp.dtype),
         grid=grid,
+        name="heat_ftcs_2d_coltiled",
         in_specs=[smem] + in_specs,
         out_specs=out_spec,
         compiler_params=pltpu.CompilerParams(
@@ -722,24 +739,18 @@ def _multistep(T: jax.Array, r: float, ksteps: int,
         if plan[0] == "thin":
             n_pad = _round_up(max(logical[1], 128), 128)
             cap = _thin_chunk_cap(n_pad, str(T.dtype))
-            done = 0
-            while done < ksteps:
-                k = min(cap, ksteps - done)
+            for k in _pass_depths(ksteps, cap):
                 T = _pallas_2d(T, r=float(r), ksteps=k, bounds=bounds)
-                done += k
             return T
         _, R, C, kr, kc, kchunk = plan
         aligned = (_round_up(max(logical[0], R), R),
                    _round_up(max(logical[1], C), C))
         if aligned != logical:
             T = jnp.pad(T, [(0, p - s) for p, s in zip(aligned, logical)])
-        done = 0
-        while done < ksteps:
-            k = min(kchunk, ksteps - done)
+        for k in _pass_depths(ksteps, kchunk):
             T = _pallas_2d_coltiled(T, r=float(r), ksteps=k, R=R, C=C,
                                     kr=kr, kc=kc, logical_shape=logical,
                                     bounds=bounds)
-            done += k
         if aligned != logical:
             T = T[: logical[0], : logical[1]]
         return T
@@ -750,15 +761,42 @@ def _multistep(T: jax.Array, r: float, ksteps: int,
     aligned, _, _, kchunk = plan
     if aligned != logical:
         T = jnp.pad(T, [(0, p - s) for p, s in zip(aligned, logical)])
-    done = 0
-    while done < ksteps:
-        k = min(kchunk, ksteps - done)
+    for k in _pass_depths(ksteps, kchunk):
         T = _pallas_3d_aligned(T, r=float(r), ksteps=k, kplan=kchunk,
                                logical_shape=logical, bounds=bounds)
-        done += k
     if aligned != logical:
         T = T[: logical[0], : logical[1], : logical[2]]
     return T
+
+
+def multistep_points(shape, dtype_str: str, ksteps: int) -> int:
+    """Point-steps ``_multistep`` computes for ``ksteps`` steps on an array
+    of ``shape``, from the plan it runs: each pass's band (halo rows or
+    blocks, alignment padding) times the mini-steps computed on it. The
+    useful work is ``prod(shape) * ksteps``; the rest is halo recompute
+    and padding."""
+    shape = tuple(shape)
+    if len(shape) == 2:
+        m, n = shape
+        plan = _plan_2d(shape, dtype_str, ksteps)
+        if plan[0] == "thin":
+            n_pad = _round_up(max(n, 128), 128)
+            total = 0
+            for k in _pass_depths(ksteps, _thin_chunk_cap(n_pad, dtype_str)):
+                n_pad, kpad, tile, m_pad = _thin_geometry(m, n, k, dtype_str)
+                total += (m_pad // tile) * (tile + 2 * kpad) * n_pad * k
+            return total
+        _, R, C, kr, kc, _ = plan
+        tiles = (_round_up(max(m, R), R) // R) * (_round_up(max(n, C), C) // C)
+        return tiles * (R + 2 * kr) * (C + 2 * kc) * ksteps
+    (m_pad, mid_pad, n_pad), R, M, kchunk = _plan_3d(shape, dtype_str, ksteps)
+    km = _round_up(kchunk, _sublane(dtype_str))
+    total = 0
+    for k in _pass_depths(ksteps, kchunk):
+        # the 3D body's mini-step s updates the band shrunk by s+1 a side
+        total += sum((R + 2 * kchunk - 2 * (s + 1)) * (M + 2 * km - 2 * (s + 1))
+                     for s in range(k))
+    return (m_pad // R) * (mid_pad // M) * n_pad * total
 
 
 def ftcs_multistep_bounded_pallas(T: jax.Array, r: float, ksteps: int,
@@ -815,6 +853,20 @@ def periodic_pad_width(shape, ksteps: int) -> int:
     return max(1, min(cap, max(ksteps, 1), min(shape)))
 
 
+def _periodic_passes(shape, dtype, ksteps: int):
+    """Wrap-ring width (= depth) of each pass of the periodic multistep, or
+    None when a wrap-padded shape has no kernel plan and XLA steps instead.
+    Gates on EVERY wrap-padded shape the loop builds: the full chunks and
+    the remainder pad differently, and for 3D a plan for the cap-padded
+    shape does not guarantee one for the smaller remainder shape
+    (_multistep asserts rather than falls back)."""
+    depths = _pass_depths(ksteps, periodic_pad_width(shape, ksteps))
+    if not all(pallas_available(tuple(s + 2 * w for s in shape), dtype)
+               for w in set(depths)):
+        return None
+    return depths
+
+
 def ftcs_multistep_periodic_pallas(T: jax.Array, r: float, ksteps: int) -> jax.Array:
     """``ksteps`` FTCS steps on the torus via the bounded kernel.
 
@@ -830,28 +882,18 @@ def ftcs_multistep_periodic_pallas(T: jax.Array, r: float, ksteps: int) -> jax.A
     if ksteps <= 0:
         return T
     nd = T.ndim
-    cap = periodic_pad_width(T.shape, ksteps)
-    # gate on EVERY wrap-padded shape the chunk loop will build — the full
-    # chunks (cap) and the remainder chunk pad differently, and for 3D a
-    # plan for the cap-padded shape does not guarantee one for the smaller
-    # remainder shape (_multistep asserts rather than falls back)
-    last = ksteps % cap or cap
-    widths = {min(cap, ksteps), last}
-    if not all(pallas_available(tuple(s + 2 * w for s in T.shape), T.dtype)
-               for w in widths):
+    depths = _periodic_passes(T.shape, T.dtype, ksteps)
+    if depths is None:
         out = T
         for _ in range(ksteps):
             out = ftcs_step_periodic(out, r)
         return out
     bounds = jnp.asarray([[-_NO_FREEZE, _NO_FREEZE] * nd], jnp.int32)
-    done = 0
-    while done < ksteps:
-        k = min(cap, ksteps - done)
+    for k in depths:
         padded = jnp.pad(T, k, mode="wrap")
         out = _multistep(padded, r, k, bounds=bounds)
         ctr = tuple(slice(k, -k) for _ in range(nd))
         T = out[ctr]
-        done += k
     return T
 
 
@@ -874,6 +916,26 @@ def ftcs_multistep_ghost_pallas(T: jax.Array, r: float, bc_value, ksteps: int) -
     for _ in range(ksteps):
         out = ftcs_step_ghost(out, r, bc_value)
     return out
+
+
+def multistep_points_bc(shape, dtype_str: str, bc: str,
+                        ksteps: int) -> int | None:
+    """Point-steps the kernels compute for ``ftcs_multistep_<bc>_pallas``
+    of ``ksteps`` steps on a field of ``shape`` (``multistep_points`` over
+    the array each BC form hands ``_multistep``), or None where that call
+    steps with XLA."""
+    shape = tuple(shape)
+    if bc == "periodic":
+        depths = _periodic_passes(shape, dtype_str, ksteps)
+        if depths is None:
+            return None
+        return sum(multistep_points(tuple(s + 2 * k for s in shape),
+                                    dtype_str, k) for k in depths)
+    if not pallas_available(shape, dtype_str):
+        return None
+    if bc == "ghost":
+        shape = tuple(s + 2 for s in shape)
+    return multistep_points(shape, dtype_str, ksteps)
 
 
 # --------------------------------------------------------------------------
@@ -1134,6 +1196,7 @@ def _lane_pallas_2d(fields: jax.Array, r, n, rem, bc_lo: int, ksteps: int,
                    jax.ShapeDtypeStruct((1, L), jnp.int32),
                    jax.ShapeDtypeStruct((4, L), jnp.float32)),
         grid=grid,
+        name="heat_lane_2d",
         in_specs=[
             smem, smem, smem,
             halo(lambda l, i: (l, jnp.maximum(i * ratio - 1, 0), 0)),
@@ -1294,6 +1357,7 @@ def _lane_pallas_3d(fields: jax.Array, r, n, rem, bc_lo: int, ksteps: int,
                    jax.ShapeDtypeStruct((1, L), jnp.int32),
                    jax.ShapeDtypeStruct((4, L), jnp.float32)),
         grid=grid,
+        name="heat_lane_3d",
         in_specs=[smem, smem, smem] + in_specs,
         out_specs=(out_spec,
                    pl.BlockSpec((1, L), lambda l, i, j: (0, 0),

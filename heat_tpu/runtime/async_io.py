@@ -141,7 +141,8 @@ class SnapshotWriter:
         # request/checkpoint made visible on the same timeline as the
         # compute it overlaps. Callers label jobs by setting a
         # ``job._trace = (name, trace_id)`` attribute; unlabeled jobs
-        # trace as "io-job". No tracer (the default) costs nothing.
+        # trace as "io-job", and a job labeled None records its own span
+        # (Tracer.span). No tracer (the default) costs nothing.
         self._tracer = tracer
         self._q: "queue.Queue[Optional[Callable[[], None]]]" = queue.Queue(
             maxsize=max(1, depth))
@@ -206,11 +207,10 @@ class SnapshotWriter:
                     self.busy_s += time.perf_counter() - t0
                     self.completed += 1
                     tr = self._tracer
-                    if tr is not None and tr.enabled:
-                        name, xid = getattr(job, "_trace",
-                                            ("io-job", None))
-                        tr.complete(name, tr.thread_track("writer"), t0,
-                                    cat="io", trace_id=xid)
+                    label = getattr(job, "_trace", ("io-job", None))
+                    if tr is not None and tr.enabled and label is not None:
+                        tr.complete(label[0], tr.thread_track("writer"), t0,
+                                    cat="io", trace_id=label[1])
             finally:
                 self._q.task_done()
 

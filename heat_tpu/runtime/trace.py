@@ -50,6 +50,15 @@ Event model (Chrome trace-event format, the subset Perfetto renders):
 Tracks are registered names: one *process* row per bucket group with one
 *thread* row per lane (the lane occupancy timeline), plus process rows
 for the scheduler / writer / gateway threads and the admission queues.
+
+**Two sinks, one span.** ``Tracer.span`` is the span for work a block
+encloses: it opens ``jax.profiler.TraceAnnotation`` (a TraceMe, so the
+span lands on the ``/host:CPU`` plane of an active profiler capture, on
+the device planes' clock) and on exit appends the same interval to the
+ring. ``SPANS`` names every such span; ``COUNTERS`` names the tracer's
+monotone totals (``Tracer.count`` / ``Tracer.counts``). Spans with no
+enclosing block (lane occupancy, queue wait, host-estimated device idle)
+stay ring-only through ``complete``.
 """
 
 from __future__ import annotations
@@ -60,6 +69,7 @@ import json
 import os
 import threading
 import time
+import weakref
 from pathlib import Path
 from typing import Dict, List, Optional, Tuple
 
@@ -89,6 +99,77 @@ def process_uptime_s() -> float:
     return time.monotonic() - PROCESS_START
 
 
+# The program's spans (``Tracer.span``): one name on the ring and on the
+# profiler's host plane. Name -> the work it encloses.
+SPANS: Dict[str, str] = {
+    "heat.drive.solve": "drive(): one solve, compile through the final fetch",
+    "heat.drive.compile": "drive(): AOT compile of the chunk programs",
+    "heat.drive.dispatch": "drive(): one chunk program enqueued (args k)",
+    "heat.drive.sync": "drive(): the fence after the last chunk",
+    "heat.drive.fetch": "drive(): the final field's device-to-host fetch",
+    "heat.sched.dispatch": "serve scheduler: one lane chunk enqueued",
+    "heat.sched.boundary_fetch": "serve scheduler: one boundary-vector fetch",
+    "heat.lane.load": "LaneEngine.load_lane: a request's field into a lane",
+    "heat.lane.writeback": "writer thread: a finished lane's extract and "
+                           "publish",
+}
+
+# The tracer's counters (``Tracer.count``): monotone totals, each written
+# by one thread. Name -> what one dispatch adds.
+COUNTERS: Dict[str, str] = {
+    "heat.drive.points_useful": "drive(): n^ndim x steps of the chunk",
+    "heat.kernel.points_computed": "drive(): point-steps the kernel "
+                                   "computes for the chunk, halo and "
+                                   "alignment padding included",
+    "heat.lane.points_useful": "lane chunk: sum over live lanes of "
+                               "n^ndim x the steps each still takes",
+    "heat.lane.points_computed": "lane chunk: lanes x slab points x steps",
+    "heat.lane.slots": "lane chunk: the lane slots stepped",
+    "heat.lane.live": "lane chunk: the slots holding a live request",
+}
+
+_TRACE_ME = None
+
+
+def _trace_me(name: str):
+    """A ``jax.profiler.TraceAnnotation`` (imported on first use: reading
+    a trace file needs no JAX)."""
+    global _TRACE_ME
+    if _TRACE_ME is None:
+        from jax.profiler import TraceAnnotation
+
+        _TRACE_ME = TraceAnnotation
+    return _TRACE_ME(name)
+
+
+class _Span:
+    """One ``Tracer.span``: a TraceMe for the profiler, and on exit the
+    same interval as an "X" event on the ring (when the tracer records
+    and a track was given). ``args`` may be set inside the block."""
+
+    __slots__ = ("tracer", "name", "track", "cat", "args", "trace_id",
+                 "_me", "_t0")
+
+    def __init__(self, tracer, name, track, cat, args, trace_id):
+        self.tracer, self.name, self.track = tracer, name, track
+        self.cat, self.args, self.trace_id = cat, args, trace_id
+
+    def __enter__(self):
+        self._me = _trace_me(self.name)
+        self._me.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        self._me.__exit__(*exc)
+        tr, track = self.tracer, self.track
+        if tr.enabled and track is not None:
+            tr._append((self._t0, t1 - self._t0, "X", self.name, self.cat,
+                        track[0], track[1], self.trace_id, self.args))
+        return False
+
+
 # Event tuples: (ts, dur, ph, name, cat, pid, tid, xid, args)
 #   ts/dur   seconds on the time.perf_counter clock (the scheduler's
 #            wall_clock seam uses the same clock, so queue-wait spans can
@@ -96,6 +177,18 @@ def process_uptime_s() -> float:
 #   ph       Chrome phase: X i b e s t f C
 #   xid      trace/flow/async id (string) or None
 #   args     small dict or None — the caller must not mutate it afterwards
+
+_TRACERS: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+
+
+def counts_total() -> Dict[str, int]:
+    """The counters summed over every live tracer of the process: the
+    solo path's global one and each serving engine's own."""
+    out = dict.fromkeys(COUNTERS, 0)
+    for t in list(_TRACERS):
+        for name, n in t.counts().items():
+            out[name] += n
+    return out
 
 
 class Tracer:
@@ -124,6 +217,10 @@ class Tracer:
                                             # never grep the filesystem)
         self.dropped_hint = False           # ring wrapped at least once
         self._appended = 0
+        # COUNTERS' totals; kept with recording off (HEAT_TPU_TRACE=off
+        # empties only the ring)
+        self._counts: Dict[str, int] = dict.fromkeys(COUNTERS, 0)
+        _TRACERS.add(self)
         # race sanitizer (no-op unless HEAT_TPU_RACECHECK): the exempt
         # trio is the allow-marked lock-free ring — _append stays a
         # zero-instrumentation hot path even when the sanitizer is armed
@@ -166,6 +263,26 @@ class Tracer:
     # --- recording (the hot path) -----------------------------------------
     def now(self) -> float:
         return time.perf_counter()
+
+    def span(self, name: str, track: Optional[Tuple[int, int]],
+             cat: str = "serve", args: Optional[dict] = None,
+             trace_id: Optional[str] = None) -> _Span:
+        """Context manager: the enclosed block as one span named ``name``
+        (a ``SPANS`` key) on the profiler's host plane and, on exit, on
+        the ring's ``track`` (None: profiler only)."""
+        return _Span(self, name, track, cat, args, trace_id)
+
+    def count(self, name: str, n: int) -> None:
+        """Add ``n`` to the ``COUNTERS`` total ``name``. Each counter has
+        one writing thread (the solving thread for ``heat.drive.*`` and
+        ``heat.kernel.*``, the scheduler thread for ``heat.lane.*``)."""
+        # one writer per key; an item store is GIL-atomic and counts()
+        # copies the dict whole
+        self._counts[name] += n
+
+    def counts(self) -> Dict[str, int]:
+        """A snapshot of the counters' totals."""
+        return dict(self._counts)
 
     def complete(self, name: str, track: Tuple[int, int], t0: float,
                  t1: Optional[float] = None, cat: str = "serve",
@@ -441,8 +558,9 @@ def summarize(chrome: dict, top: int = 5) -> List[str]:
                          f"class {args.get('class', '?')}, "
                          f"policy {args.get('policy', '?')})")
 
-    for name, label in (("boundary-fetch", "boundary-fetch wall"),
-                        ("device-idle", "device-idle wall")):
+    for name, label in (("heat.sched.boundary_fetch", "boundary-fetch wall"),
+                        ("device-idle", "device-idle wall"),
+                        ("heat.drive.fetch", "solve fetch wall")):
         tot = sum(e.get("dur", 0.0) for e in data
                   if e.get("ph") == "X" and e.get("name") == name)
         n = sum(1 for e in data if e.get("ph") == "X"

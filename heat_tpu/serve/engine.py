@@ -107,12 +107,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 import time
 from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
 from ..ops.stencil import accum_dtype_for, laplacian_interior
+from ..runtime import trace as trace_mod
 from ..utils import jnp_dtype
 
 # BC -> first request-interior offset that updates: ghost updates every
@@ -455,12 +457,17 @@ class LaneEngine:
     keyed by (bucket, lane-tier, k); ``on_compile(k, seconds)`` fires for
     every program actually built so the scheduler's compile accounting
     (one stepping compile per combo, plus at most one tail) stays exact.
+
+    ``tracer``/``track`` (runtime/trace.py) take each lane load's
+    ``heat.lane.load`` span: the process-global tracer and no ring track
+    (profiler only) unless the scheduler passes its own.
     """
 
     def __init__(self, key: BucketKey, lanes: int, chunk: int,
                  compiled_cache: Optional[Dict] = None,
                  on_compile: Optional[Callable[[int, float], None]] = None,
-                 kernel: str = "xla", donate: bool = True):
+                 kernel: str = "xla", donate: bool = True,
+                 tracer=None, track: Optional[Tuple[int, int]] = None):
         import jax.numpy as jnp
 
         if key.bc not in _BC_LO:
@@ -495,6 +502,10 @@ class LaneEngine:
             self._lane_shape = shape
         else:
             self._lane_shape = key.padded_shape
+        # points one lane slab holds: what a chunk computes per lane-step
+        self.slab_points = math.prod(self._lane_shape)
+        self._tracer = tracer if tracer is not None else trace_mod.get_tracer()
+        self._track = track
         dt = jnp_dtype(key.dtype)
         acc = accum_dtype_for(dt)
         self._state = (
@@ -553,18 +564,20 @@ class LaneEngine:
         round-to-nearest-even the XLA convert would apply.)"""
         dt = jnp_dtype(self.key.dtype)
         acc = accum_dtype_for(dt)
-        buf = lane_buffer(self.key, field, bc_value).astype(dt)
-        if buf.shape != self._lane_shape:
-            # pallas layout: embed the bucket buffer in the kernel-aligned
-            # slab corner; the zero alignment padding is frozen by the
-            # per-lane bounds (finite, never read by a live cell)
-            slab = np.zeros(self._lane_shape, dtype=dt)
-            slab[tuple(slice(0, s) for s in buf.shape)] = buf
-            buf = slab
-        self._state = self._load(
-            *self._state, np.int32(lane), buf,
-            np.asarray(r, acc), np.int32(field.shape[0]),
-            np.int32(steps))
+        with self._tracer.span("heat.lane.load", self._track, cat="lane",
+                               args={"lane": lane}):
+            buf = lane_buffer(self.key, field, bc_value).astype(dt)
+            if buf.shape != self._lane_shape:
+                # pallas layout: embed the bucket buffer in the kernel-
+                # aligned slab corner; the zero alignment padding is frozen
+                # by the per-lane bounds (finite, never read by a live cell)
+                slab = np.zeros(self._lane_shape, dtype=dt)
+                slab[tuple(slice(0, s) for s in buf.shape)] = buf
+                buf = slab
+            self._state = self._load(
+                *self._state, np.int32(lane), buf,
+                np.asarray(r, acc), np.int32(field.shape[0]),
+                np.int32(steps))
 
     def snapshot_lane(self, lane: int):
         """One-lane ON-DEVICE copy of a finished lane (the PR-1 snapshot

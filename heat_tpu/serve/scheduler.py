@@ -525,11 +525,23 @@ class _GroupRunner:
         # chunk copy program on the dispatch path — engine.snapshot_stack)
         self.kernel, self._kernel_fb = resolve_lane_kernel(
             scfg.lane_kernel, key)
+        # trace tracks (runtime/trace.py): one process row per bucket
+        # group, one thread row per lane (the occupancy timeline) plus a
+        # dispatch row for dispatch, chunk-in-flight, lane-load and
+        # device-idle spans. Registered here, once, so the per-event path
+        # is append-only.
+        self.tracer = outer.tracer
+        self.track_name = (f"lanes {key.ndim}d n{key.n} "
+                           f"{key.dtype} {key.bc}")
+        self.group_track = self.tracer.track(self.track_name, "dispatch")
+        self.lane_tracks = [self.tracer.track(self.track_name, f"lane {i}")
+                            for i in range(self.lanes)]
         self.eng = LaneEngine(key, self.lanes, scfg.chunk,
                               compiled_cache=outer._compiled,
                               on_compile=outer._note_compile,
                               kernel=self.kernel,
-                              donate=not self.rollback)
+                              donate=not self.rollback,
+                              tracer=self.tracer, track=self.group_track)
         if self._kernel_fb is not None:
             outer._note_lane_fallback(key, self.lanes, self._kernel_fb)
         self.occupant: List[Optional[Request]] = [None] * self.lanes
@@ -571,16 +583,6 @@ class _GroupRunner:
                                     # sizes runners from the full queue,
                                     # so growth (and its pipeline drain)
                                     # must never perturb the batch shape
-        # trace tracks (runtime/trace.py): one process row per bucket
-        # group, one thread row per lane (the occupancy timeline) plus a
-        # dispatch row for chunk-in-flight / device-idle spans. Registered
-        # here, once, so the per-event path is append-only.
-        self.tracer = outer.tracer
-        self.track_name = (f"lanes {key.ndim}d n{key.n} "
-                           f"{key.dtype} {key.bc}")
-        self.group_track = self.tracer.track(self.track_name, "dispatch")
-        self.lane_tracks = [self.tracer.track(self.track_name, f"lane {i}")
-                            for i in range(self.lanes)]
         self._fill()
 
     # --- admission into lanes --------------------------------------------
@@ -765,7 +767,11 @@ class _GroupRunner:
                 k = tail
                 self.outer.tail_chunks += 1
             t_disp = wall_clock()
-            handle = self.eng.dispatch_chunk(k)
+            with self.tracer.span("heat.sched.dispatch", self.group_track,
+                                  cat="dispatch",
+                                  args={"seq": self.seq, "k": k}):
+                handle = self.eng.dispatch_chunk(k)
+            self._count_chunk(k)
             if self.idle_from is not None:
                 self.outer.device_idle_s += t_disp - self.idle_from
                 if self.tracer.enabled:
@@ -789,27 +795,42 @@ class _GroupRunner:
             self.seq += 1
             self.outer.chunks_dispatched += 1
 
+    def _count_chunk(self, k: int) -> None:
+        """The lane counters (runtime/trace.py COUNTERS) of one k-step chunk
+        over every slot. Call before the countdown mirror moves: a live lane
+        takes min(k, its remaining) steps of the chunk."""
+        live = useful = 0
+        for req, rem in zip(self.occupant, self.dev_rem):
+            if req is not None and rem > 0:
+                live += 1
+                useful += req.cfg.points * min(k, int(rem))
+        tr = self.tracer
+        tr.count("heat.lane.points_useful", useful)
+        tr.count("heat.lane.points_computed",
+                 self.lanes * self.eng.slab_points * k)
+        tr.count("heat.lane.slots", self.lanes)
+        tr.count("heat.lane.live", live)
+
     # --- boundary side ----------------------------------------------------
     def _fetch(self, handle) -> np.ndarray:
         """One watchdog-bounded boundary fetch with wall accounting."""
         outer = self.outer
         t0 = wall_clock()
+        tr = self.tracer
         try:
-            return self.eng.fetch_remaining(
-                handle, timeout_s=outer.scfg.fetch_timeout_s,
-                plan=outer._plan, fetch_index=outer._fetch_seq)
+            # boundary_wait_s, attributed: each fetch's blocked wall
+            # becomes one span on the scheduler thread's row
+            with tr.span("heat.sched.boundary_fetch",
+                         tr.thread_track("scheduler") if tr.enabled
+                         else None,
+                         cat="boundary", args={"bucket": self.track_name}):
+                return self.eng.fetch_remaining(
+                    handle, timeout_s=outer.scfg.fetch_timeout_s,
+                    plan=outer._plan, fetch_index=outer._fetch_seq)
         finally:
             outer._fetch_seq += 1
-            t1 = wall_clock()
-            outer.boundary_wait_s += t1 - t0
+            outer.boundary_wait_s += wall_clock() - t0
             outer.boundary_waits += 1
-            if self.tracer.enabled:
-                # boundary_wait_s, attributed: each fetch's blocked wall
-                # becomes one span on the scheduler thread's row
-                self.tracer.complete("boundary-fetch",
-                                     self.tracer.thread_track("scheduler"),
-                                     t0, t1, cat="boundary",
-                                     args={"bucket": self.track_name})
 
     def _trace_occupancy(self, lane: int, req: Request, status: str) -> None:
         """Close the lane's occupancy span (admission -> this verdict) on
@@ -1141,7 +1162,8 @@ class _GroupRunner:
                               compiled_cache=outer._compiled,
                               on_compile=outer._note_compile,
                               kernel=self.kernel,
-                              donate=not self.rollback)
+                              donate=not self.rollback,
+                              tracer=self.tracer, track=self.group_track)
         if self._kernel_fb is not None:
             # the fallback contract is per (bucket, tier): the grown tier
             # is a new compiled program that also fell back
@@ -1196,7 +1218,12 @@ class _GroupRunner:
                 if self.tracer.enabled:
                     self.tracer.complete("device-idle", self.group_track,
                                          self.idle_from, t0, cat="idle")
-            b = self._fetch(self.eng.dispatch_chunk())
+            with self.tracer.span("heat.sched.dispatch", self.group_track,
+                                  cat="dispatch",
+                                  args={"seq": self.seq, "k": self.chunk}):
+                handle = self.eng.dispatch_chunk()
+            self._count_chunk(self.chunk)
+            b = self._fetch(handle)
             rem, finite = b[0], b[1]
             outer.chunks_dispatched += 1
             self.idle_from = wall_clock()
@@ -1440,7 +1467,10 @@ class MegaLaneRunner:
                 self._maybe_poison()
             k = min(self.chunk, rem)
             t_disp = wall_clock()
-            handle = self.eng.dispatch_chunk(k)
+            with self.tracer.span("heat.sched.dispatch", self.group_track,
+                                  cat="dispatch",
+                                  args={"seq": self.seq, "k": k}):
+                handle = self.eng.dispatch_chunk(k)
             if self.idle_from is not None:
                 outer.device_idle_s += t_disp - self.idle_from
                 if self.tracer.enabled:
@@ -1460,20 +1490,19 @@ class MegaLaneRunner:
     def _fetch(self, handle) -> np.ndarray:
         outer = self.outer
         t0 = wall_clock()
+        tr = self.tracer
         try:
-            return engine_fetch_boundary(
-                handle, timeout_s=outer.scfg.fetch_timeout_s,
-                plan=outer._plan, fetch_index=outer._fetch_seq)
+            with tr.span("heat.sched.boundary_fetch",
+                         tr.thread_track("scheduler") if tr.enabled
+                         else None,
+                         cat="boundary", args={"bucket": self.track_name}):
+                return engine_fetch_boundary(
+                    handle, timeout_s=outer.scfg.fetch_timeout_s,
+                    plan=outer._plan, fetch_index=outer._fetch_seq)
         finally:
             outer._fetch_seq += 1
-            t1 = wall_clock()
-            outer.boundary_wait_s += t1 - t0
+            outer.boundary_wait_s += wall_clock() - t0
             outer.boundary_waits += 1
-            if self.tracer.enabled:
-                self.tracer.complete("boundary-fetch",
-                                     self.tracer.thread_track("scheduler"),
-                                     t0, t1, cat="boundary",
-                                     args={"bucket": self.track_name})
 
     def _trace_occupancy(self, lane: int, req: Request, status: str) -> None:
         tr = self.tracer
@@ -1742,7 +1771,11 @@ class MegaLaneRunner:
                 if self.tracer.enabled:
                     self.tracer.complete("device-idle", self.group_track,
                                          self.idle_from, t0, cat="idle")
-            b = self._fetch(self.eng.dispatch_chunk(k))
+            with self.tracer.span("heat.sched.dispatch", self.group_track,
+                                  cat="dispatch",
+                                  args={"seq": self.seq, "k": k}):
+                handle = self.eng.dispatch_chunk(k)
+            b = self._fetch(handle)
             rem_vec, finite = b[0], b[1]
             outer.chunks_dispatched += 1
             self.idle_from = wall_clock()
@@ -3325,59 +3358,65 @@ class Engine:
         # actual step count (ntime, or the steady-exit frontier)
         steps_done = rec.get("steps_done")
 
-        def job():
-            # Runs in the writer thread. Transient sink errors are
-            # re-raised so the SnapshotWriter's bounded in-thread retry
-            # (backoff, same budget as checkpoints) gets its shot; a final
-            # failure is recorded on THIS request and swallowed — it must
-            # not poison writer._exc and kill the other lanes' drain.
-            attempts["n"] += 1
-            try:
-                T = get_field()
-                plan = faults.plan_for(cfg)
-                if plan is not None:
-                    plan.sink_fault(cfg.ntime)
-                path = (str(_write_result(scfg.out_dir, req.id, T, cfg,
-                                          steps=steps_done))
-                        if scfg.out_dir else None)
-                # bytes the tenant's result cost: the published file's
-                # size, or the in-memory field bytes when nothing hits
-                # disk — finalized HERE (writer thread) so the ledger add
-                # at emission sees the complete stamp
-                from pathlib import Path as _Path
+        tr = self.tracer
 
-                nbytes = (_Path(path).stat().st_size if path is not None
-                          else int(T.nbytes))
-                with self._lock:
-                    if scfg.keep_fields or not scfg.out_dir:
-                        rec["T"] = T
-                    if path is not None:
-                        rec["path"] = path
-                    rec["status"] = "ok"
-                    rec["usage"]["bytes_written"] = int(nbytes)
-                # solve-cache population (ISSUE 19), on the writer
-                # thread after the publish landed: a byte copy of the
-                # published artifact (or the identical serialization
-                # when nothing hit disk), keyed under the ACTUAL step
-                # count — a steady early exit caches under its exit
-                # frontier so later fixed-step requests can prefix-hit
-                # it. Best effort: put() swallows its own failures.
-                if self.solvecache is not None:
-                    self.solvecache.put(
-                        cfg, int(cfg.ntime if steps_done is None
-                                 else steps_done),
-                        T=T, src_path=path, kind="result")
-            except BaseException as e:  # noqa: BLE001 — per-request record
-                if async_io.is_transient(e) and attempts["n"] <= writer.retries:
-                    raise
-                with self._lock:
-                    rec["status"] = "error"
-                    rec["error"] = f"{type(e).__name__}: {e}"
+        def job():
+            # Runs in the writer thread, as one heat.lane.writeback span
+            # (snapshot D2H + atomic publish, on the writer's own track).
+            # Transient sink errors are re-raised so the SnapshotWriter's
+            # bounded in-thread retry (backoff, same budget as checkpoints)
+            # gets its shot; a final failure is recorded on THIS request
+            # and swallowed — it must not poison writer._exc and kill the
+            # other lanes' drain.
+            attempts["n"] += 1
+            with tr.span("heat.lane.writeback",
+                         tr.thread_track("writer") if tr.enabled else None,
+                         cat="io", args={"id": req.id},
+                         trace_id=rec.get("trace_id")):
+                try:
+                    T = get_field()
+                    plan = faults.plan_for(cfg)
+                    if plan is not None:
+                        plan.sink_fault(cfg.ntime)
+                    path = (str(_write_result(scfg.out_dir, req.id, T, cfg,
+                                              steps=steps_done))
+                            if scfg.out_dir else None)
+                    # bytes the tenant's result cost: the published file's
+                    # size, or the in-memory field bytes when nothing hits
+                    # disk — finalized HERE (writer thread) so the ledger add
+                    # at emission sees the complete stamp
+                    from pathlib import Path as _Path
+
+                    nbytes = (_Path(path).stat().st_size if path is not None
+                              else int(T.nbytes))
+                    with self._lock:
+                        if scfg.keep_fields or not scfg.out_dir:
+                            rec["T"] = T
+                        if path is not None:
+                            rec["path"] = path
+                        rec["status"] = "ok"
+                        rec["usage"]["bytes_written"] = int(nbytes)
+                    # solve-cache population, on the writer
+                    # thread after the publish landed: a byte copy of the
+                    # published artifact (or the identical serialization
+                    # when nothing hit disk), keyed under the ACTUAL step
+                    # count — a steady early exit caches under its exit
+                    # frontier so later fixed-step requests can prefix-hit
+                    # it. Best effort: put() swallows its own failures.
+                    if self.solvecache is not None:
+                        self.solvecache.put(
+                            cfg, int(cfg.ntime if steps_done is None
+                                     else steps_done),
+                            T=T, src_path=path, kind="result")
+                except BaseException as e:  # noqa: BLE001 — per-request record
+                    if async_io.is_transient(e) and attempts["n"] <= writer.retries:
+                        raise
+                    with self._lock:
+                        rec["status"] = "error"
+                        rec["error"] = f"{type(e).__name__}: {e}"
             self._emit(rec)
 
-        # the writer thread labels its span with the request it serves
-        # (snapshot D2H + atomic publish, on the writer's own track)
-        job._trace = (f"writeback {req.id}", rec.get("trace_id"))
+        job._trace = None        # the job records its own span
         writer.submit(job)
 
     def _finish_async(self, eng: LaneEngine, lane: int, req: Request,

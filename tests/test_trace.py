@@ -208,9 +208,12 @@ def test_trace_export_schema_and_span_taxonomy(tmp_path):
 
     # span taxonomy: the tracks and spans the README documents
     names = {e["name"] for e in evs if e["ph"] == "X"}
-    assert {"boundary-fetch", "engine.run"} <= names
+    assert {"heat.sched.boundary_fetch", "heat.sched.dispatch",
+            "heat.lane.load", "heat.lane.writeback", "engine.run"} <= names
     assert any(n.startswith("chunk ") for n in names)
-    assert any(n.startswith("writeback ") for n in names)
+    written = {e["args"]["id"] for e in evs
+               if e["ph"] == "X" and e["name"] == "heat.lane.writeback"}
+    assert written == set(ids)   # one writeback span per request
     for rid in ids:
         assert rid in names      # one occupancy span per request
     procs = {e["args"]["name"] for e in evs
