@@ -59,12 +59,23 @@ def make_advance(cfg: HeatConfig):
         step = lambda t: ftcs_step_ghost_pallas(t, r, bc_value)
         multi = lambda t, k: ftcs_multistep_ghost_pallas(t, r, bc_value, k)
 
+    # Two kernel passes per loop iteration. A pass cannot step in place
+    # (each row tile reads its neighbours' halo rows), so it writes a fresh
+    # buffer; with one pass a body, XLA copies that buffer back into the
+    # loop carry after every pass. With two, the first pass's input is dead
+    # when the second writes, so the second writes the carry's own buffer.
+    # An odd pass count costs one field copy a call, before the loop.
+    def two_passes(i, t):
+        return multi(multi(t, kf), kf)
+
     @functools.partial(jax.jit, static_argnums=1, donate_argnums=0)
     def advance(T, k: int):
-        n_fused, rem = divmod(k, kf)
-        if kf > 1 and n_fused:
-            T = jax.lax.fori_loop(0, n_fused, lambda i, t: multi(t, kf), T)
-        return run_steps(T, rem if kf > 1 else k, step)
+        n_fused, rem = divmod(k, kf) if kf > 1 else (0, k)
+        if n_fused % 2:
+            T = multi(T, kf)
+        if n_fused // 2:
+            T = jax.lax.fori_loop(0, n_fused // 2, two_passes, T)
+        return run_steps(T, rem, step)
 
     # drive() counts the kernels' computed point-steps per chunk from this
     advance.points_computed = functools.partial(kernel_points, cfg)

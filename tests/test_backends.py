@@ -137,6 +137,48 @@ def test_pallas_backend_with_fusion_matches_oracle(bc, ic):
     np.testing.assert_allclose(got.T, expect.T, rtol=0, atol=5e-6)
 
 
+@pytest.mark.parametrize("n,bc,dtype", [(40, "edges", "float32"),
+                                        (64, "ghost", "float32"),
+                                        (40, "periodic", "float32"),
+                                        (64, "edges", "bfloat16")])
+@pytest.mark.parametrize("rem", [0, 2])
+@pytest.mark.parametrize("n_fused", range(5))
+def test_pallas_advance_is_the_sequential_passes(n, bc, dtype, rem, n_fused):
+    """The backend's fused loop (two passes an iteration, an odd pass
+    peeled) runs the same kernel calls in the same order as calling the
+    BC's multistep ``n_fused`` times and its single step ``rem`` times:
+    the answer is bit for bit the same."""
+    import jax.numpy as jnp
+
+    from heat_tpu.backends.pallas import make_advance
+    from heat_tpu.grid import initial_condition
+    from heat_tpu.ops import pallas_stencil as ps
+
+    kf = 3
+    cfg = HeatConfig(n=n, dtype=dtype, ic="hat", bc=bc, bc_value=0.5,
+                     backend="pallas", fuse_steps=kf)
+    r = cfg.r
+    multi, step = {
+        "edges": (lambda t: ps.ftcs_multistep_edges_pallas(t, r, kf),
+                  lambda t: ps.ftcs_step_edges_pallas(t, r)),
+        "ghost": (lambda t: ps.ftcs_multistep_ghost_pallas(t, r, 0.5, kf),
+                  lambda t: ps.ftcs_step_ghost_pallas(t, r, 0.5)),
+        "periodic": (lambda t: ps.ftcs_multistep_periodic_pallas(t, r, kf),
+                     lambda t: ps.ftcs_step_periodic_pallas(t, r)),
+    }[bc]
+    T = jnp.asarray(initial_condition(cfg), dtype)
+    expect = T
+    for _ in range(n_fused):
+        expect = multi(expect)
+    for _ in range(rem):
+        expect = step(expect)
+    got = make_advance(cfg)(jnp.copy(T), n_fused * kf + rem)
+    got, expect, T = (np.asarray(a, np.float32) for a in (got, expect, T))
+    np.testing.assert_array_equal(got, expect)
+    if n_fused or rem:
+        assert not np.array_equal(got, T)
+
+
 def test_multistep_fallback_when_k_exceeds_tile():
     import jax.numpy as jnp
 

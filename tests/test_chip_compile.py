@@ -12,6 +12,8 @@ auto depth 16 takes minutes at these widths — PERF.md). The topology is
 described inside a fixture, never at import: only one process may load
 libtpu, and the driver's workers all import this file."""
 
+import re
+
 import pytest
 
 # Topology compiles cannot round-trip the persistent compile cache; jax
@@ -66,23 +68,60 @@ def _check(compiled) -> int:
     return total
 
 
-def _solo(one_chip, n: int, k: int):
+def _solo(one_chip, n: int, k: int, passes: int = 4):
     import jax
     import jax.numpy as jnp
 
     from heat_tpu.backends.pallas import make_advance
     from heat_tpu.config import HeatConfig
 
-    cfg = HeatConfig(n=n, ntime=4 * k, dtype="float32", backend="pallas",
+    cfg = HeatConfig(n=n, ntime=passes * k, dtype="float32", backend="pallas",
                      fuse_steps=k)
     x = jax.ShapeDtypeStruct((n, n), jnp.float32, sharding=one_chip)
-    return make_advance(cfg).lower(x, 4 * k).compile()
+    return make_advance(cfg).lower(x, passes * k).compile()
+
+
+def _computations(hlo: str) -> dict:
+    """The compiled module's computations by name, each as its text."""
+    comps, name = {}, None
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%(\S+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            comps[name] = []
+        elif name is not None:
+            comps[name].append(line)
+    return {k: "\n".join(v) for k, v in comps.items()}
+
+
+def _field_copies(text: str, shape: str) -> list:
+    """Names of the copy instructions in ``text`` that produce ``shape``."""
+    return re.findall(rf"%(\S+) = {re.escape(shape)}\S* copy(?:-start)?\(",
+                      text)
 
 
 def test_2d_kernel_4096_f32(one_chip):
     """The reference's single-GPU shape (python/cuda/cuda.py:31-33)."""
     total = _check(_solo(one_chip, 4096, 4))
     assert total >= 2 * 4096 * 4096 * 4   # in + out fields at least
+
+
+@pytest.mark.parametrize("passes", [25, 24])
+def test_2d_fused_loop_4096_keeps_no_carry_copy(one_chip, passes):
+    """A kernel pass writes a fresh buffer, so a loop of one pass an
+    iteration copies it back into the loop carry after every pass (64 MiB
+    read and written per pass at 4096²). Two passes an iteration leave the
+    loop body without a full-field copy; an odd pass count costs at most
+    one copy for the whole program."""
+    hlo = _solo(one_chip, 4096, 4, passes).as_text()
+    comps = _computations(hlo)
+    bodies = re.findall(r"while\(.*?body=%([\w.\-]+)", hlo)
+    assert bodies, "no fused loop in the program"
+    for body in bodies:
+        assert body in comps, body
+        assert not _field_copies(comps[body], "f32[4096,4096]"), body
+        assert comps[body].count("custom-call(") == 2, body
+    assert len(_field_copies(hlo, "f32[4096,4096]")) <= 1
 
 
 def test_2d_plan_at_flagship_32768_f32(one_chip):
