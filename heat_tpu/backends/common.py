@@ -226,6 +226,9 @@ def _drive(cfg, T_dev, advance, tracer, drv_track, start_step, to_host,
     # backend reports what its kernels compute for a k-step chunk, if it can
     points_computed = getattr(advance, "points_computed", None)
     computed_by_k: dict = {}
+    # and, on a mesh, its halo exchanges and the bytes they send
+    halo_counts = getattr(advance, "halo_counts", None)
+    halo_by_k: dict = {}
 
     # AOT-compile every chunk size the loop will encounter (at most two: the
     # steady chunk and a final remainder) so no compile lands inside the
@@ -369,6 +372,12 @@ def _drive(cfg, T_dev, advance, tracer, drv_track, start_step, to_host,
                         if computed_by_k[k] is not None:
                             tracer.count("heat.kernel.points_computed",
                                          computed_by_k[k])
+                    if halo_counts is not None:
+                        if k not in halo_by_k:
+                            halo_by_k[k] = halo_counts(k)
+                        exchanges, sent = halo_by_k[k]
+                        tracer.count("heat.halo.exchanges", exchanges)
+                        tracer.count("heat.halo.bytes", sent)
                     if plan is not None:
                         plan.maybe_crash(step)
                         T_dev = plan.maybe_nan(step, T_dev)

@@ -49,15 +49,29 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..config import HeatConfig
 from ..ops.pallas_stencil import (_KMAX_2D, _NO_FREEZE,
                                   ftcs_multistep_bounded_pallas,
-                                  pallas_available)
+                                  multistep_points, pallas_available)
 from ..ops.stencil import accum_dtype_for, laplacian_interior
-from ..parallel.halo import (halo_exchange, halo_exchange_indep, halo_pad,
-                             halo_recvs)
+from ..parallel.halo import (exchange_bytes, halo_exchange,
+                             halo_exchange_indep, halo_pad, halo_recvs)
 from ..parallel.mesh import build_mesh, validate_divisible
 from ..runtime.logging import master_print
 from ..utils import jnp_dtype
 from . import SolveResult, register
 from .common import drive, host_fetch, resolve_initial_field
+
+
+def _local_kernel_is_pallas(cfg: HeatConfig) -> bool:
+    """Whether each shard steps with the Pallas kernel (else XLA)."""
+    kernel_ok = pallas_available((cfg.n,) * cfg.ndim, jnp_dtype(cfg.dtype))
+    if cfg.local_kernel == "pallas" and not kernel_ok:
+        raise ValueError(
+            f"local_kernel='pallas' does not support dtype={cfg.dtype!r} "
+            f"(no f64 on the TPU VPU); use local_kernel='xla' or 'auto'")
+    return cfg.local_kernel == "pallas" or (
+        cfg.local_kernel == "auto"
+        and jax.default_backend() == "tpu"
+        and kernel_ok
+    )
 
 
 def make_local_multistep(cfg: HeatConfig, axis_names, axis_sizes):
@@ -75,17 +89,7 @@ def make_local_multistep(cfg: HeatConfig, axis_names, axis_sizes):
     periodic = cfg.bc == "periodic"
     n = cfg.n
 
-    kernel_ok = pallas_available((cfg.n,) * cfg.ndim, jnp_dtype(cfg.dtype))
-    if cfg.local_kernel == "pallas" and not kernel_ok:
-        raise ValueError(
-            f"local_kernel='pallas' does not support dtype={cfg.dtype!r} "
-            f"(no f64 on the TPU VPU); use local_kernel='xla' or 'auto'")
-    use_pallas = cfg.local_kernel == "pallas" or (
-        cfg.local_kernel == "auto"
-        and jax.default_backend() == "tpu"
-        and kernel_ok
-    )
-
+    use_pallas = _local_kernel_is_pallas(cfg)
     overlap = cfg.exchange == "overlap"
     if overlap and not use_pallas:
         raise ValueError(
@@ -931,6 +935,11 @@ def make_padded_carry_machinery(cfg: HeatConfig, mesh):
     # margins stay width kf across calls; only the step count shrinks on
     # the remainder chunk
     advance = _chunked_advance(mesh, lambda p, k: padded_multi(p, kf, k), kf)
+    # drive() counts the kernels' point-steps and the exchanges per chunk
+    advance.points_computed = functools.partial(
+        carried_points, cfg, axis_sizes, kf)
+    advance.halo_counts = functools.partial(
+        carried_halo_counts, cfg, axis_sizes, kf)
 
     @jax.jit
     def crop(Tp):
@@ -938,6 +947,46 @@ def make_padded_carry_machinery(cfg: HeatConfig, mesh):
             lambda p: p[tuple(slice(kf, -kf) for _ in range(p.ndim))])(Tp)
 
     return seed, advance, crop
+
+
+def _carried_blocks(k: int, kf: int) -> list:
+    """(steps, count) of the fused blocks ``_chunked_advance`` runs for a
+    chunk of ``k`` steps: ``k // kf`` blocks of kf and one of the rest."""
+    n_fused, rem = divmod(k, kf)
+    return [(s, c) for s, c in ((kf, n_fused), (rem, 1)) if s and c]
+
+
+def carried_points(cfg: HeatConfig, axis_sizes, kf: int,
+                   k: int) -> Optional[int]:
+    """Point-steps the local kernels of all shards compute for one
+    padded-carry chunk of ``k`` steps: each block steps the whole padded
+    shard (owned cells plus the kf-wide ghost margins), the Pallas kernel
+    by its own plan (band halo rows and alignment padding included), the
+    XLA step cell by cell. None for the overlap exchange, whose interior
+    and rim regions are not counted."""
+    if cfg.exchange == "overlap":
+        return None
+    shape = tuple(cfg.n // s + 2 * kf for s in axis_sizes)
+    pallas = _local_kernel_is_pallas(cfg)
+    per_block = 0
+    for steps, count in _carried_blocks(k, kf):
+        per_block += count * (
+            multistep_points(shape, cfg.dtype, steps) if pallas
+            else int(np.prod(shape)) * steps)
+    return int(np.prod(axis_sizes)) * per_block
+
+
+def carried_halo_counts(cfg: HeatConfig, axis_sizes, kf: int,
+                        k: int) -> tuple:
+    """``(exchanges, bytes)`` one device runs and sends for a padded-carry
+    chunk of ``k`` steps: one kf-wide exchange a fused block, the bytes
+    averaged over the devices (``halo.exchange_bytes``)."""
+    shape = tuple(cfg.n // s + 2 * kf for s in axis_sizes)
+    exchanges = sum(c for _, c in _carried_blocks(k, kf))
+    per = exchange_bytes(shape, axis_sizes, kf,
+                         jnp.dtype(jnp_dtype(cfg.dtype)).itemsize,
+                         periodic=cfg.bc == "periodic")
+    return exchanges, exchanges * per
 
 
 def make_mega_machinery(cfg: HeatConfig, mesh):

@@ -54,6 +54,8 @@ def build_parser() -> argparse.ArgumentParser:
     run.add_argument("--ic", choices=["hat", "hat_half", "hat_small", "uniform", "zero"])
     run.add_argument("--bc", choices=["edges", "ghost", "periodic"])
     run.add_argument("--bc-value", type=float)
+    run.add_argument("--ntime", type=int,
+                     help="time steps, in place of the input file's")
     run.add_argument("--ndim", type=int, choices=[2, 3])
     run.add_argument("--comm", choices=["direct", "staged"],
                      help="halo exchange: device-direct (CUDA-aware analog) "
@@ -728,7 +730,8 @@ def _apply_overrides(cfg: HeatConfig, args) -> HeatConfig:
     subcommand exposing a subset of run's flags (``plan``) reuses this
     instead of hand-rolling a drifting copy."""
     over = {}
-    for field in ("backend", "dtype", "ic", "bc", "ndim", "comm", "exchange",
+    for field in ("backend", "dtype", "ic", "bc", "ntime", "ndim", "comm",
+                  "exchange",
                   "fuse_steps", "local_kernel", "heartbeat_every",
                   "checkpoint_every", "checkpoint_dir", "async_io",
                   "profile_dir", "write_int", "on_nan", "inject"):

@@ -41,17 +41,46 @@ def _stage_through_host(x: jax.Array) -> jax.Array:
     )
 
 
-def _shift_from_prev(slab, axis_name: str, size: int, periodic: bool = False):
-    """Each shard receives the slab of its left/previous neighbor."""
+# the name the exchange's collectives carry in the program's op metadata
+SCOPE = "heat_halo_exchange"
+
+
+def _pairs(size: int, periodic: bool, from_prev: bool) -> list:
+    """(source, destination) shard pairs of one shift along an axis."""
     pairs = [(i, (i + 1) % size) for i in range(size)] if periodic else [
         (i, i + 1) for i in range(size - 1)]
-    return lax.ppermute(slab, axis_name, pairs)
+    return pairs if from_prev else [(d, s) for s, d in pairs]
+
+
+def _shift_from_prev(slab, axis_name: str, size: int, periodic: bool = False):
+    """Each shard receives the slab of its left/previous neighbor."""
+    with jax.named_scope(SCOPE):
+        return lax.ppermute(slab, axis_name, _pairs(size, periodic, True))
 
 
 def _shift_from_next(slab, axis_name: str, size: int, periodic: bool = False):
-    pairs = [((i + 1) % size, i) for i in range(size)] if periodic else [
-        (i + 1, i) for i in range(size - 1)]
-    return lax.ppermute(slab, axis_name, pairs)
+    with jax.named_scope(SCOPE):
+        return lax.ppermute(slab, axis_name, _pairs(size, periodic, False))
+
+
+def exchange_bytes(padded_shape: Sequence[int], axis_sizes: Sequence[int],
+                   width: int, itemsize: int, periodic: bool = False) -> int:
+    """Bytes one exchange of a ``width``-deep ghost ring sends from one
+    device to another, averaged over the mesh's devices (to the nearest
+    byte). For each axis every ppermute pair between two different shards
+    carries one send slab: ``width`` deep on that axis and the full padded
+    extent of the others, as ``halo_exchange`` and ``halo_recvs`` cut it.
+    An edge shard of a non-periodic axis has one such pair, an inner shard
+    two; a periodic axis of one shard sends to itself, which moves
+    nothing between devices."""
+    total = 0.0
+    for d, size in enumerate(axis_sizes):
+        slab = int(np.prod(padded_shape)) // padded_shape[d] * width
+        crossing = sum(s != t for prev in (True, False)
+                       for s, t in _pairs(size, periodic, prev))
+        # ``crossing`` sends in each of the mesh's lines along axis d
+        total += crossing * slab * itemsize / size
+    return int(round(total))
 
 
 def halo_exchange(

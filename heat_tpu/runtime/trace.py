@@ -121,6 +121,10 @@ COUNTERS: Dict[str, str] = {
     "heat.kernel.points_computed": "drive(): point-steps the kernel "
                                    "computes for the chunk, halo and "
                                    "alignment padding included",
+    "heat.halo.exchanges": "drive() on a mesh: the halo exchanges one "
+                           "device runs in the chunk",
+    "heat.halo.bytes": "drive() on a mesh: the bytes one device sends in "
+                       "those exchanges, mean of the devices",
     "heat.lane.points_useful": "lane chunk: sum over live lanes of "
                                "n^ndim x the steps each still takes",
     "heat.lane.points_computed": "lane chunk: lanes x slab points x steps",

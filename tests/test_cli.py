@@ -45,6 +45,16 @@ def test_run_variant_preset(input_dat, capsys):
     assert '"backend": "xla"' in out
 
 
+def test_run_ntime_overrides_the_input_file(input_dat, capsys):
+    """The reference's flagship is cut in steps only: ``--ntime`` runs
+    input.dat's grid for another step count."""
+    rc = main(["run", "--backend", "sharded", "--mesh", "2x2", "--dtype",
+               "float32", "--bc", "ghost", "--fuse-steps", "4", "--ntime",
+               "7", "--json"])
+    assert rc == 0
+    assert '"ntime": 7' in capsys.readouterr().out
+
+
 def test_run_sharded_with_mesh(input_dat):
     rc = main(["run", "--backend", "sharded", "--dtype", "float64",
                "--mesh", "4x2", "--report-sum"])
