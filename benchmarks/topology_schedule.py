@@ -111,11 +111,11 @@ def main():
     import jax
 
     jax.config.update("jax_platforms", "cpu")  # works chipless by design
-    import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from heat_tpu.backends.sharded import make_padded_carry_machinery
+    from heat_tpu.backends.sharded import (_probe_state_struct,
+                                           fuse_depth_sharded,
+                                           make_padded_carry_machinery)
     from heat_tpu.config import HeatConfig
     from heat_tpu.ops.pallas_stencil import force_compiled_kernels
     from heat_tpu.parallel.mesh import build_mesh  # noqa: F401 (parity cite)
@@ -147,10 +147,8 @@ def main():
                              fuse_steps=args.fuse, exchange=ex,
                              local_kernel="pallas")
             _, advance, _ = make_padded_carry_machinery(cfg, mesh)
-            shape = tuple(args.n + 2 * args.fuse * s for s in mesh_shape)
-            struct = jax.ShapeDtypeStruct(
-                shape, jnp.float32,
-                sharding=NamedSharding(mesh, P(*mesh.axis_names)))
+            struct = _probe_state_struct(
+                cfg, mesh, fuse_depth_sharded(cfg, mesh_shape), padded=True)
             t0 = time.perf_counter()
             try:
                 txt = advance.lower(struct, args.steps).compile().as_text()

@@ -55,14 +55,11 @@ def _mem(compiled) -> dict:
 
 
 def _compile_case(topology, mesh_shape, cfg, steps):
-    import jax
-    import jax.numpy as jnp
     from jax.experimental import topologies
-    from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from heat_tpu.backends.sharded import (fuse_depth_sharded,
+    from heat_tpu.backends.sharded import (_probe_state_struct,
+                                           fuse_depth_sharded,
                                            make_padded_carry_machinery)
-    from heat_tpu.utils import jnp_dtype
 
     names = tuple("xyz"[: len(mesh_shape)])
     topo = topologies.get_topology_desc(topology, "tpu")
@@ -84,9 +81,7 @@ def _compile_case(topology, mesh_shape, cfg, steps):
                     names)
     kf = fuse_depth_sharded(cfg, mesh_shape)
     _, advance, _ = make_padded_carry_machinery(cfg, mesh)
-    struct = jax.ShapeDtypeStruct(
-        tuple(cfg.n + 2 * kf * s for s in mesh_shape), jnp_dtype(cfg.dtype),
-        sharding=NamedSharding(mesh, P(*mesh.axis_names)))
+    struct = _probe_state_struct(cfg, mesh, kf, padded=True)
     t0 = time.perf_counter()
     compiled = advance.lower(struct, steps).compile()
     return compiled, time.perf_counter() - t0, kf

@@ -49,7 +49,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ..config import HeatConfig
 from ..ops.pallas_stencil import (_KMAX_2D, _NO_FREEZE,
                                   ftcs_multistep_bounded_pallas,
-                                  multistep_points, pallas_available)
+                                  multistep_points, pallas_available,
+                                  thin_carry_shape)
 from ..ops.stencil import accum_dtype_for, laplacian_interior
 from ..parallel.halo import (exchange_bytes, halo_exchange,
                              halo_exchange_indep, halo_pad, halo_recvs)
@@ -104,17 +105,18 @@ def make_local_multistep(cfg: HeatConfig, axis_names, axis_sizes):
     def _shard_bounds(padded_shape, wpad: int) -> list:
         """Per-axis [lo, hi] freeze bounds in PADDED shard coordinates:
         only global-domain edges freeze (Dirichlet ghosts, plus the
-        boundary ring under "edges" semantics); the wpad-cell discard
-        margin owns all array-edge garbage. Traced values (axis_index)."""
+        boundary ring under "edges" semantics), and every cell at or
+        beyond the logical padded extent (alignment cells, which keep
+        their zeros); the wpad-cell discard margin owns all array-edge
+        garbage. Traced values (axis_index)."""
         edges = 1 if cfg.bc == "edges" else 0
         bounds = []
         for d, name in enumerate(axis_names):
-            if periodic:  # torus: nothing frozen anywhere
-                bounds.extend([jnp.int32(-_NO_FREEZE),
-                               jnp.int32(_NO_FREEZE)])
+            M = padded_shape[d]
+            if periodic:  # torus: no global edge, every shard is inner
+                bounds.extend([jnp.int32(-1), jnp.int32(M)])
                 continue
             coord = jax.lax.axis_index(name)
-            M = padded_shape[d]
             bounds.append(jnp.where(coord == 0, wpad - 1 + edges, -1))
             bounds.append(jnp.where(coord == axis_sizes[d] - 1,
                                     M - wpad - edges, M))
@@ -126,13 +128,19 @@ def make_local_multistep(cfg: HeatConfig, axis_names, axis_sizes):
         output's ghost margins are garbage — the next exchange rewrites
         every margin cell before anything reads them). This is the
         pad-free core: the padded-carry solve path calls it directly so
-        the per-exchange pad+crop copy of the whole block disappears."""
+        the per-exchange pad+crop copy of the whole block disappears.
+
+        ``padded`` is the logical padded shard, or that shard at the
+        origin of the kernel's aligned array (``carried_shapes``): the
+        exchange and the freeze bounds then keep to the logical extent,
+        and the kernel steps the aligned array with no pad or slice."""
+        logical = tuple(n // s + 2 * wpad for s in axis_sizes)
         padded0 = exchange_fn(
             padded, axis_names, axis_sizes, bc_value,
-            staged=staged, width=wpad, periodic=periodic,
+            staged=staged, width=wpad, periodic=periodic, logical=logical,
         )
         if use_pallas:
-            bounds = _shard_bounds(padded.shape, wpad)
+            bounds = _shard_bounds(logical, wpad)
             return ftcs_multistep_bounded_pallas(
                 padded0, r, ksteps, jnp.stack(bounds).astype(jnp.int32))
 
@@ -599,7 +607,9 @@ def _compile_probe(cfg: HeatConfig, mesh, kf: int, remaining: int,
 
 def _probe_state_struct(cfg: HeatConfig, mesh, kf: int, padded: bool):
     """The sharded state ShapeDtypeStruct the probe compiles against."""
-    shape = (tuple(cfg.n + 2 * kf * int(s) for s in mesh.devices.shape)
+    sizes = mesh.devices.shape
+    shape = (tuple(c * int(s) for c, s in
+                   zip(carried_shapes(cfg, sizes, kf)[1], sizes))
              if padded else cfg.shape)
     return jax.ShapeDtypeStruct(
         shape, jnp_dtype(cfg.dtype),
@@ -870,20 +880,41 @@ def _auto_chunk_2d(cfg: HeatConfig, axis_sizes) -> int:
     return effective_chunk_2d((rows, cols), cfg.dtype)
 
 
-def _chunked_advance(mesh, step, kf: int):
+def _fused_blocks(block, t, count: int, paired: bool):
+    """``count`` applications of ``block`` in a loop, two an iteration if
+    ``paired``. A block that ends in a kernel call on the whole carried
+    array (the aligned carry, ``carried_shapes``) cannot step in place: the
+    kernel reads its neighbours' halo rows, so it writes a fresh buffer,
+    and with one block a body XLA copies that buffer back into the loop
+    carry after every block. With two, the first block's input is dead
+    when the second writes, so the second writes the carry's own buffer;
+    an odd count peels one block before the loop. Elsewhere one block a
+    body is kept: the XLA local kernel's loop gains copies when paired."""
+    if not paired:
+        if count:
+            t = jax.lax.fori_loop(0, count, lambda i, t: block(t), t)
+        return t
+    if count % 2:
+        t = block(t)
+    if count // 2:
+        t = jax.lax.fori_loop(0, count // 2, lambda i, t: block(block(t)), t)
+    return t
+
+
+def _chunked_advance(mesh, step, kf: int, paired: bool = False):
     """Jitted, donated k-step advance: fused blocks of ``kf`` steps + one
     remainder call, via ``step(local_state, nsteps)`` inside shard_map —
     the ONE chunking scheme both the owned-state and padded-carry paths
-    use (only the step callable differs)."""
+    use (only the step callable differs). ``paired`` as in
+    ``_fused_blocks``."""
     spec = P(*mesh.axis_names)
 
     @functools.partial(jax.jit, static_argnums=1, donate_argnums=0)
     def advance(Tg, k: int):
         def body(local):
             n_fused, rem = divmod(k, kf)
-            if n_fused:
-                local = jax.lax.fori_loop(
-                    0, n_fused, lambda i, t: step(t, kf), local)
+            local = _fused_blocks(lambda t: step(t, kf), local, n_fused,
+                                  paired)
             if rem:
                 local = step(local, rem)
             return local
@@ -901,6 +932,38 @@ def make_advance(cfg: HeatConfig, mesh):
     return _chunked_advance(mesh, local_multi, kf)
 
 
+def carried_shapes(cfg: HeatConfig, axis_sizes, kf: int) -> tuple:
+    """``(logical, carried)`` local shapes of one shard on the padded-carry
+    path at halo width ``kf``. ``logical`` is the padded shard: the owned
+    cells and a kf-wide ghost margin on every side. ``carried`` is the
+    array that holds it, with the logical shard at its origin: the
+    thin-band kernel's aligned shape where each shard steps with that
+    kernel (``thin_carry_shape``), so a fused block runs no pad or slice
+    of the whole shard; the logical shape everywhere else (the XLA local
+    kernel, the col-tiled plan, 3D, the overlap exchange)."""
+    logical = tuple(cfg.n // int(s) + 2 * kf for s in axis_sizes)
+    if (cfg.ndim == 2 and cfg.exchange != "overlap"
+            and _local_kernel_is_pallas(cfg)):
+        return logical, thin_carry_shape(logical, cfg.dtype, kf) or logical
+    return logical, logical
+
+
+def _carry_seed_crop(bc_value, kf: int, logical, carried):
+    """Per-shard bodies of the padded-carry entry and exit: ``seed`` pads
+    the owned shard once with ``bc_value`` ghosts and zero alignment
+    cells (``carried_shapes``), ``crop`` cuts the owned shard back out."""
+    align = [(0, c - l) for l, c in zip(logical, carried)]
+
+    def seed(local):
+        padded = halo_pad(local, bc_value, kf)
+        return jnp.pad(padded, align) if carried != logical else padded
+
+    def crop(p):
+        return p[tuple(slice(kf, l - kf) for l in logical)]
+
+    return seed, crop
+
+
 def make_padded_carry_machinery(cfg: HeatConfig, mesh):
     """(seed, advance, crop) carrying the PADDED field as solve state.
 
@@ -912,12 +975,17 @@ def make_padded_carry_machinery(cfg: HeatConfig, mesh):
     classic path (same exchange, same kernel, same bounds). The same
     padded-state idea the parity machinery uses for w=1 ghosts
     (make_parity_machinery), here at the communication-avoiding width.
+    Where each shard steps with the thin-band kernel, the shard is carried
+    in that kernel's aligned shape from seed to crop (``carried_shapes``),
+    so the kernel's own per-call pad and slice of the shard go too.
     """
     axis_names = mesh.axis_names
     axis_sizes = mesh.devices.shape
     _, padded_multi = make_local_multistep(cfg, axis_names, axis_sizes)
     kf = fuse_depth_sharded(cfg, axis_sizes)
-    bc_value = cfg.bc_value
+    logical, carried = carried_shapes(cfg, axis_sizes, kf)
+    seed_local, crop_local = _carry_seed_crop(cfg.bc_value, kf, logical,
+                                              carried)
     spec = P(*axis_names)
     smap = functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
                              out_specs=spec, check_vma=False)
@@ -929,12 +997,12 @@ def make_padded_carry_machinery(cfg: HeatConfig, mesh):
         # padded state. (Donation can't help here — the padded output is a
         # different shape, so the input buffer is never reusable and JAX
         # warns.)
-        return jax.jit(smap(lambda local: halo_pad(local, bc_value, kf)))(
-            T_owned)
+        return jax.jit(smap(seed_local))(T_owned)
 
     # margins stay width kf across calls; only the step count shrinks on
     # the remainder chunk
-    advance = _chunked_advance(mesh, lambda p, k: padded_multi(p, kf, k), kf)
+    advance = _chunked_advance(mesh, lambda p, k: padded_multi(p, kf, k), kf,
+                               paired=carried != logical)
     # drive() counts the kernels' point-steps and the exchanges per chunk
     advance.points_computed = functools.partial(
         carried_points, cfg, axis_sizes, kf)
@@ -943,8 +1011,7 @@ def make_padded_carry_machinery(cfg: HeatConfig, mesh):
 
     @jax.jit
     def crop(Tp):
-        return smap(
-            lambda p: p[tuple(slice(kf, -kf) for _ in range(p.ndim))])(Tp)
+        return smap(crop_local)(Tp)
 
     return seed, advance, crop
 
@@ -966,7 +1033,8 @@ def carried_points(cfg: HeatConfig, axis_sizes, kf: int,
     and rim regions are not counted."""
     if cfg.exchange == "overlap":
         return None
-    shape = tuple(cfg.n // s + 2 * kf for s in axis_sizes)
+    # the kernel's plan is the logical shard's, whichever array carries it
+    shape = carried_shapes(cfg, axis_sizes, kf)[0]
     pallas = _local_kernel_is_pallas(cfg)
     per_block = 0
     for steps, count in _carried_blocks(k, kf):
@@ -980,8 +1048,9 @@ def carried_halo_counts(cfg: HeatConfig, axis_sizes, kf: int,
                         k: int) -> tuple:
     """``(exchanges, bytes)`` one device runs and sends for a padded-carry
     chunk of ``k`` steps: one kf-wide exchange a fused block, the bytes
-    averaged over the devices (``halo.exchange_bytes``)."""
-    shape = tuple(cfg.n // s + 2 * kf for s in axis_sizes)
+    averaged over the devices (``halo.exchange_bytes``), whose slabs span
+    the logical shard whichever array carries it."""
+    shape = carried_shapes(cfg, axis_sizes, kf)[0]
     exchanges = sum(c for _, c in _carried_blocks(k, kf))
     per = exchange_bytes(shape, axis_sizes, kf,
                          jnp.dtype(jnp_dtype(cfg.dtype)).itemsize,
@@ -1024,14 +1093,15 @@ def make_mega_machinery(cfg: HeatConfig, mesh):
     axis_sizes = mesh.devices.shape
     _, padded_multi = make_local_multistep(cfg, axis_names, axis_sizes)
     kf = fuse_depth_sharded(cfg, axis_sizes)
-    bc_value = cfg.bc_value
+    logical, carried = carried_shapes(cfg, axis_sizes, kf)
+    seed_local, crop_local = _carry_seed_crop(cfg.bc_value, kf, logical,
+                                              carried)
     nd = cfg.ndim
     spec = P(*axis_names)
     smap = functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
                              check_vma=False)
 
-    seed = jax.jit(smap(lambda local: halo_pad(local, bc_value, kf),
-                        out_specs=spec))
+    seed = jax.jit(smap(seed_local, out_specs=spec))
 
     from ..serve.engine import pack_boundary
 
@@ -1040,10 +1110,8 @@ def make_mega_machinery(cfg: HeatConfig, mesh):
         def body(padded):
             if k > 1:
                 n_fused, r_ = divmod(k - 1, kf)
-                if n_fused:
-                    padded = jax.lax.fori_loop(
-                        0, n_fused, lambda i, t: padded_multi(t, kf, kf),
-                        padded)
+                padded = _fused_blocks(lambda t: padded_multi(t, kf, kf),
+                                       padded, n_fused, carried != logical)
                 if r_:
                     padded = padded_multi(padded, kf, r_)
             prev = padded
@@ -1052,7 +1120,7 @@ def make_mega_machinery(cfg: HeatConfig, mesh):
             # are invariant under chunk partitioning, so the field
             # bytes match the one-shot chunk body exactly
             padded = padded_multi(padded, kf, 1)
-            ctr = tuple(slice(kf, -kf) for _ in range(nd))
+            ctr = tuple(slice(kf, l - kf) for l in logical)
             # per-shard owned-interior health bit + numerics stats:
             # reading only (never writing) the stepped state, so
             # bit-identity is untouched — the PR-5 lane-engine
@@ -1075,9 +1143,7 @@ def make_mega_machinery(cfg: HeatConfig, mesh):
                            heat.sum()]).astype(jnp.float32).reshape(4, 1)
         return Tp, rem2, pack_boundary(rem2, finite, stats)
 
-    crop = jax.jit(smap(
-        lambda p: p[tuple(slice(kf, -kf) for _ in range(nd))],
-        out_specs=spec))
+    crop = jax.jit(smap(crop_local, out_specs=spec))
     return seed, advance, crop, kf
 
 

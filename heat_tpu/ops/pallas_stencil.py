@@ -799,6 +799,34 @@ def multistep_points(shape, dtype_str: str, ksteps: int) -> int:
     return (m_pad // R) * (mid_pad // M) * n_pad * total
 
 
+def thin_carry_shape(shape, dtype_str: str, ksteps: int):
+    """The aligned ``(m_pad, n_pad)`` array the thin-band kernel runs every
+    pass of ``ksteps`` fused steps on, for a logical 2D ``shape``; None
+    where the planner picks another kernel, the passes differ in geometry,
+    or the plan at the aligned shape is not that same thin plan. A caller
+    that keeps its state in this shape, with the logical array at its
+    origin, and freezes every cell beyond it, runs ``_multistep`` on the
+    state as it is: no pad or slice around the kernel."""
+    shape = tuple(shape)
+    if len(shape) != 2 or not pallas_available(shape, dtype_str):
+        return None
+    plan = _plan_2d(shape, dtype_str, ksteps)
+    if plan[0] != "thin":
+        return None
+    m, n = shape
+    cap = _thin_chunk_cap(_round_up(max(n, 128), 128), dtype_str)
+    geoms = {_thin_geometry(m, n, k, dtype_str)
+             for k in _pass_depths(ksteps, cap)}
+    if len(geoms) != 1:
+        return None
+    n_pad, _, _, m_pad = geoms.pop()
+    # the planner sees the aligned array, not the logical one: it must
+    # pick the same thin plan there, which then pads nothing
+    if _plan_2d((m_pad, n_pad), dtype_str, ksteps) != plan:
+        return None
+    return (m_pad, n_pad)
+
+
 def ftcs_multistep_bounded_pallas(T: jax.Array, r: float, ksteps: int,
                                   bounds: jax.Array) -> jax.Array:
     """``ksteps`` fused FTCS steps freezing cells at or beyond ``bounds``

@@ -83,6 +83,15 @@ def exchange_bytes(padded_shape: Sequence[int], axis_sizes: Sequence[int],
     return int(round(total))
 
 
+def _cut(padded: jax.Array, logical, d: int, sl_d: slice) -> tuple:
+    """Index of a slab of ``padded`` that is ``sl_d`` on axis ``d`` and
+    spans the ``logical`` extent of the others (the whole array where the
+    two agree)."""
+    return tuple(sl_d if e == d else
+                 slice(None) if logical[e] == padded.shape[e] else
+                 slice(0, logical[e]) for e in range(padded.ndim))
+
+
 def halo_exchange(
     padded: jax.Array,
     axis_names: Sequence[str],
@@ -91,6 +100,7 @@ def halo_exchange(
     staged: bool = False,
     width: int = 1,
     periodic: bool = False,
+    logical: Sequence[int] | None = None,
 ) -> jax.Array:
     """Refresh a ``width``-cell ghost ring of a padded local shard.
 
@@ -108,21 +118,23 @@ def halo_exchange(
     ghost regions hold true diagonal-neighbor data (needed by fused
     multi-step updates; a single 5/7-point step never reads corners, so
     ng=1 behavior is unchanged).
+
+    ``logical`` (default: the whole array) is the extent of the padded
+    shard at ``padded``'s origin, where ``padded`` carries alignment cells
+    beyond it: slabs are cut and written inside that extent.
     """
-    nd = padded.ndim
     w = width
+    L = tuple(padded.shape if logical is None else logical)
     bc = jnp.asarray(bc_value, padded.dtype)
     out = padded
     for d, (name, size) in enumerate(zip(axis_names, axis_sizes)):
         idx = lax.axis_index(name)
 
         def slab(sl_d):
-            sl = [slice(None)] * nd
-            sl[d] = sl_d
-            return tuple(sl)
+            return _cut(padded, L, d, sl_d)
 
         send_lo = out[slab(slice(w, 2 * w))]       # first owned planes -> prev
-        send_hi = out[slab(slice(-2 * w, -w))]     # last owned planes  -> next
+        send_hi = out[slab(slice(L[d] - 2 * w, L[d] - w))]  # last -> next
         if staged:
             send_lo = _stage_through_host(send_lo)
             send_hi = _stage_through_host(send_hi)
@@ -138,7 +150,7 @@ def halo_exchange(
             from_next = jnp.where(idx == size - 1, bc, from_next)
 
         out = out.at[slab(slice(0, w))].set(from_prev)
-        out = out.at[slab(slice(-w, None))].set(from_next)
+        out = out.at[slab(slice(L[d] - w, L[d]))].set(from_next)
     return out
 
 
@@ -150,6 +162,7 @@ def halo_recvs(
     staged: bool = False,
     width: int = 1,
     periodic: bool = False,
+    logical: Sequence[int] | None = None,
 ) -> dict:
     """The receive half of the indep exchange: ``{d: (from_prev,
     from_next)}`` ghost slabs, each spanning the FULL padded extent of the
@@ -165,9 +178,11 @@ def halo_recvs(
     ghosts into their margins (corner forwarding), so d's ppermutes start
     only after e<d's land — the wire windows are sequential by axis; the
     per-face consumers this function enables are what lets kernels fill
-    the later windows."""
+    the later windows. ``logical`` as in ``halo_exchange``: the slabs span
+    it, not the alignment cells beyond."""
     nd = padded.ndim
     w = width
+    L = tuple(padded.shape if logical is None else logical)
     bc = jnp.asarray(bc_value, padded.dtype)
 
     def slab(d, sl_d):
@@ -178,8 +193,8 @@ def halo_recvs(
     recvs = {}  # d -> (from_prev, from_next)
     for d, (name, size) in enumerate(zip(axis_names, axis_sizes)):
         idx = lax.axis_index(name)
-        send_lo = padded[slab(d, slice(w, 2 * w))]
-        send_hi = padded[slab(d, slice(-2 * w, -w))]
+        send_lo = padded[_cut(padded, L, d, slice(w, 2 * w))]
+        send_hi = padded[_cut(padded, L, d, slice(L[d] - 2 * w, L[d] - w))]
         # corner forwarding: overwrite the earlier-axis margins of the
         # send slab with those axes' fresh ghosts (what the sequential
         # scheme reads from the updated array)
@@ -208,21 +223,20 @@ def halo_recvs(
     return recvs
 
 
-def apply_recvs(padded: jax.Array, recvs: dict, width: int = 1) -> jax.Array:
+def apply_recvs(padded: jax.Array, recvs: dict, width: int = 1,
+                logical: Sequence[int] | None = None) -> jax.Array:
     """Write the received slabs into the ghost margins (the write half of
-    the indep exchange). Write order is increasing axis — later axes own
-    the corners — and every consumer assembling band inputs from ``recvs``
-    directly must reproduce that order (``_overlap_region_input``)."""
+    the indep exchange), inside the ``logical`` extent (``halo_exchange``).
+    Write order is increasing axis — later axes own the corners — and
+    every consumer assembling band inputs from ``recvs`` directly must
+    reproduce that order (``_overlap_region_input``)."""
     w = width
-    nd = padded.ndim
+    L = tuple(padded.shape if logical is None else logical)
     out = padded
     for d in sorted(recvs):
         from_prev, from_next = recvs[d]
-        sl = [slice(None)] * nd
-        sl[d] = slice(0, w)
-        out = out.at[tuple(sl)].set(from_prev)
-        sl[d] = slice(-w, None)
-        out = out.at[tuple(sl)].set(from_next)
+        out = out.at[_cut(padded, L, d, slice(0, w))].set(from_prev)
+        out = out.at[_cut(padded, L, d, slice(L[d] - w, L[d]))].set(from_next)
     return out
 
 
@@ -234,6 +248,7 @@ def halo_exchange_indep(
     staged: bool = False,
     width: int = 1,
     periodic: bool = False,
+    logical: Sequence[int] | None = None,
 ) -> jax.Array:
     """``halo_exchange`` with all ghost writes made independent.
 
@@ -251,8 +266,9 @@ def halo_exchange_indep(
     tests/test_sharded.py::test_halo_exchange_indep_bitwise.
     """
     recvs = halo_recvs(padded, axis_names, axis_sizes, bc_value,
-                       staged=staged, width=width, periodic=periodic)
-    return apply_recvs(padded, recvs, width=width)
+                       staged=staged, width=width, periodic=periodic,
+                       logical=logical)
+    return apply_recvs(padded, recvs, width=width, logical=logical)
 
 
 def halo_pad(local: jax.Array, bc_value, width: int = 1) -> jax.Array:

@@ -792,14 +792,13 @@ class MegaLaneEngine:
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
+        from ..backends.sharded import _probe_state_struct
+
         cfg, mesh = self.cfg, self.mesh
         sharding = NamedSharding(mesh, P(*mesh.axis_names))
-        dt = jnp_dtype(cfg.dtype)
-        owned = jax.ShapeDtypeStruct(cfg.shape, dt, sharding=sharding)
-        padded = jax.ShapeDtypeStruct(
-            tuple(cfg.n + 2 * kf * int(s) for s in mesh.devices.shape),
-            dt, sharding=sharding)
-        return owned, padded
+        owned = jax.ShapeDtypeStruct(cfg.shape, jnp_dtype(cfg.dtype),
+                                     sharding=sharding)
+        return owned, _probe_state_struct(cfg, mesh, kf, padded=True)
 
     def _machinery(self) -> dict:
         """Build (or fetch warm) the mega machinery for this (config,
@@ -931,6 +930,20 @@ class MegaLaneEngine:
         alive, only the cropped snapshot."""
         return host_fetch(snap)
 
+    def _center_index(self) -> tuple:
+        """Global index of the centre owned cell in the carried state,
+        where each shard takes ``carried_shapes``' carried extent: the
+        logical padded shard at its origin, alignment cells beyond."""
+        from ..backends.sharded import carried_shapes
+
+        sizes = self.mesh.devices.shape
+        carried = carried_shapes(self.cfg, sizes, self.kf)[1]
+        idx = []
+        for s, c in zip(sizes, carried):
+            shard, off = divmod(self.cfg.n // 2, self.cfg.n // int(s))
+            idx.append(shard * c + self.kf + off)
+        return tuple(idx)
+
     def poison_center(self) -> None:
         """Chaos-only (``lane-nan`` injection on a mega request): NaN the
         center OWNED cell of the carried padded state. Device placement
@@ -939,13 +952,7 @@ class MegaLaneEngine:
         import jax
         import jax.numpy as jnp
 
-        cfg, kf = self.cfg, self.kf
-        idx = []
-        for s in self.mesh.devices.shape:
-            local = cfg.n // int(s)
-            shard, off = divmod(cfg.n // 2, local)
-            idx.append(shard * (local + 2 * kf) + kf + off)
-        poisoned = self._state.at[tuple(idx)].set(jnp.nan)
+        poisoned = self._state.at[self._center_index()].set(jnp.nan)
         self._state = jax.device_put(poisoned, self._state.sharding)
 
     def perturb_center(self, eps: float) -> None:
@@ -957,13 +964,7 @@ class MegaLaneEngine:
         import jax
         import jax.numpy as jnp
 
-        cfg, kf = self.cfg, self.kf
-        idx = []
-        for s in self.mesh.devices.shape:
-            local = cfg.n // int(s)
-            shard, off = divmod(cfg.n // 2, local)
-            idx.append(shard * (local + 2 * kf) + kf + off)
-        bumped = self._state.at[tuple(idx)].add(
+        bumped = self._state.at[self._center_index()].add(
             jnp.asarray(eps, self._state.dtype))
         self._state = jax.device_put(bumped, self._state.sharding)
 
@@ -1076,19 +1077,16 @@ def mega_program_specs():
 
     def build():
         import jax
-        from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from ..backends.sharded import make_mega_machinery
+        from ..backends.sharded import (_probe_state_struct,
+                                        make_mega_machinery)
         from ..parallel.mesh import build_mesh
 
         cfg = HeatConfig(n=32, ndim=2, dtype="float32", bc="ghost",
                          ntime=16, backend="sharded", mesh_shape=(1, 1))
         mesh = build_mesh(cfg.ndim, cfg.mesh_shape)
         _, advance, _, kf = make_mega_machinery(cfg, mesh)
-        sharding = NamedSharding(mesh, P(*mesh.axis_names))
-        padded = jax.ShapeDtypeStruct(
-            tuple(cfg.n + 2 * kf * int(s) for s in mesh.devices.shape),
-            jnp_dtype(cfg.dtype), sharding=sharding)
+        padded = _probe_state_struct(cfg, mesh, kf, padded=True)
         rem = jax.ShapeDtypeStruct((1,), np.int32)
         return advance, (padded, rem, 8), (2,)
 

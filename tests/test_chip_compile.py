@@ -26,20 +26,33 @@ _HBM_BYTES = 16 * 2**30   # v5e: 16 GiB of HBM per chip
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     import jax
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no libtpu AOT compiler on this host
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh_2x2(topo):
+    from jax.experimental import topologies
+
+    return topologies.make_mesh(topo, (2, 2), ("x", "y"))
 
 
 @pytest.fixture(autouse=True)
@@ -94,10 +107,10 @@ def _computations(hlo: str) -> dict:
     return {k: "\n".join(v) for k, v in comps.items()}
 
 
-def _field_copies(text: str, shape: str) -> list:
-    """Names of the copy instructions in ``text`` that produce ``shape``."""
-    return re.findall(rf"%(\S+) = {re.escape(shape)}\S* copy(?:-start)?\(",
-                      text)
+def _ops(text: str, op: str, shape: str) -> list:
+    """Names of the ``op`` instructions in ``text`` that produce ``shape``."""
+    return re.findall(
+        rf"%(\S+) = {re.escape(shape)}\S* {op}(?:-start)?\(", text)
 
 
 def test_2d_kernel_4096_f32(one_chip):
@@ -119,9 +132,51 @@ def test_2d_fused_loop_4096_keeps_no_carry_copy(one_chip, passes):
     assert bodies, "no fused loop in the program"
     for body in bodies:
         assert body in comps, body
-        assert not _field_copies(comps[body], "f32[4096,4096]"), body
+        assert not _ops(comps[body], "copy", "f32[4096,4096]"), body
         assert comps[body].count("custom-call(") == 2, body
-    assert len(_field_copies(hlo, "f32[4096,4096]")) <= 1
+    assert len(_ops(hlo, "copy", "f32[4096,4096]")) <= 1
+
+
+@pytest.mark.parametrize("steps", [20, 23])
+def test_sharded_fused_loop_steps_the_aligned_carry(mesh_2x2, steps):
+    """The padded-carry loop of the 2x2 sharded solve, with a padded
+    shard whose extent is not the kernel's aligned shape: 2048² over 2x2
+    at fuse depth 4 is a 1032² shard (1024 owned plus 4-wide ghosts) in a
+    1280x1152 carry (the thin-band plan's 256-row tiles and 128-lane
+    columns). 20 steps are five fused blocks; 23 add a remainder of 3.
+    Each loop body holds an in-place exchange and two kernel calls on the
+    carry: no pad into the kernel's shape, no slice of the whole shard
+    back out of it, and at most one whole-shard copy a block: the
+    exchange's relayout of the carry to cut its column slabs
+    (``parallel/halo.py`` ``halo_recvs``), the next copy to remove. The
+    program holds at most one more outside the loop. (At 512² the shard
+    is small enough to stay in the chip's fast memory, and no relayout
+    shows.)"""
+    from heat_tpu.backends.sharded import (_probe_state_struct,
+                                           make_padded_carry_machinery)
+    from heat_tpu.config import HeatConfig
+
+    cfg = HeatConfig(n=2048, ntime=steps, dtype="float32", backend="sharded",
+                     mesh_shape=(2, 2), fuse_steps=4, local_kernel="pallas")
+    _, advance, _ = make_padded_carry_machinery(cfg, mesh_2x2)
+    struct = _probe_state_struct(cfg, mesh_2x2, 4, padded=True)
+    hlo = advance.lower(struct, steps).compile().as_text()
+    comps = _computations(hlo)
+    whole = ("f32[1032,1032]", "f32[1280,1152]")   # logical shard, carry
+    bodies = re.findall(r"while\(.*?body=%([\w.\-]+)", hlo)
+    assert bodies, "no fused loop in the program"
+    in_loop = []
+    for body in bodies:
+        text = comps[body]
+        assert " pad(" not in text, body
+        assert not [op for s in whole for op in _ops(text, "slice", s)], body
+        kernels = re.findall(r"%heat_ftcs_2d\S* = .* custom-call\(", text)
+        assert len(kernels) == 2, body
+        in_loop += [op for s in whole for op in _ops(text, "copy", s)]
+    assert len(in_loop) <= 2 * len(bodies), in_loop
+    copies = [op for s in whole for op in _ops(hlo, "copy", s)]
+    assert len(copies) <= len(in_loop) + 1, copies
+    assert not [op for s in whole for op in _ops(hlo, "pad", s)]
 
 
 def test_2d_plan_at_flagship_32768_f32(one_chip):

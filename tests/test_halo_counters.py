@@ -81,6 +81,39 @@ def test_halo_counts_are_the_exchange_slabs(mesh_shape):
     assert advance.halo_counts(23) == (6, 6 * round(per_device))
 
 
+@pytest.mark.parametrize("mesh_shape", MESHES)
+def test_counts_at_the_aligned_carry_are_the_logical_shards(mesh_shape):
+    """Where each shard steps with the thin-band kernel, the padded-carry
+    path carries it in the kernel's aligned array (a 256x128 carry for
+    every 64² split here). The counts stay those of the logical padded
+    shard: the kernel's point-steps are its plan there, and the exchange
+    sends the same slabs as the XLA local kernel's logical carry. The
+    jaxpr's own ``ppermute``s still carry the counted bytes."""
+    cfg = CFG.with_(mesh_shape=mesh_shape, local_kernel="pallas")
+    mesh, (seed, advance, _) = _machinery(cfg)
+    logical, carried = sharded.carried_shapes(cfg, mesh_shape, 4)
+    assert carried == (256, 128) != logical
+    xla = cfg.with_(local_kernel="xla")
+    assert sharded.carried_shapes(xla, mesh_shape, 4) == (logical, logical)
+    for k in (12, 23):
+        blocks = [(4, k // 4)] + ([(k % 4, 1)] if k % 4 else [])
+        assert advance.points_computed(k) == 4 * sum(
+            c * multistep_points(logical, "float32", s) for s, c in blocks)
+        assert advance.halo_counts(k) == sharded.carried_halo_counts(
+            xla, mesh_shape, 4, k)
+    Tp = seed(jnp.zeros((64, 64), jnp.float32))
+    assert Tp.shape == tuple(c * s for c, s in zip(carried, mesh_shape))
+    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
+    found = _ppermutes(jax.make_jaxpr(lambda T: advance(T, 4))(Tp).jaxpr,
+                       [])
+    per_device = 0.0
+    for axis, perm, shape, item in found:
+        axis = axis[0] if isinstance(axis, tuple) else axis
+        crossing = sum(s != t for s, t in perm)
+        per_device += crossing * int(np.prod(shape)) * item / sizes[axis]
+    assert advance.halo_counts(4) == (1, round(per_device))
+
+
 def test_halo_bytes_of_the_flagship_2x2():
     """32768^2 on 2x2 at fuse depth 4: every shard has one neighbour an
     axis, and sends it a 4 x 16392 float32 slab."""
@@ -88,6 +121,12 @@ def test_halo_bytes_of_the_flagship_2x2():
                      backend="sharded", bc="ghost", fuse_steps=4,
                      mesh_shape=(2, 2))
     assert sharded.carried_halo_counts(cfg, (2, 2), 4, 1500) == (
+        375, 375 * 2 * 4 * 16392 * 4)
+    # the same where each shard rides in the kernel's aligned 16464x16512
+    pallas = cfg.with_(local_kernel="pallas")
+    assert sharded.carried_shapes(pallas, (2, 2), 4) == (
+        (16392, 16392), (16464, 16512))
+    assert sharded.carried_halo_counts(pallas, (2, 2), 4, 1500) == (
         375, 375 * 2 * 4 * 16392 * 4)
 
 

@@ -228,6 +228,58 @@ def test_mega_rollback_recovers_transient_poison(depth):
     np.testing.assert_array_equal(recs[big]["T"], solo_sharded(MEGA_CFG))
 
 
+# float32 with the Pallas local kernel (interpret mode on the CPU): each
+# shard rides in the thin-band kernel's aligned carry, alignment cells
+# beyond its logical padded extent (backends/sharded.py carried_shapes)
+ALIGNED_MEGA = HeatConfig(n=64, ntime=37, dtype="float32", bc="edges",
+                          local_kernel="pallas")
+
+
+def test_mega_poison_and_perturb_hit_the_owned_centre_of_aligned_carry():
+    """On a 2x2 mesh whose shards ride in the aligned carry, the chaos
+    injections land on the centre OWNED cell (not on a frozen alignment
+    cell of a lower shard), and the next chunk's health bit sees it."""
+    import jax
+
+    from heat_tpu.backends.sharded import carried_shapes
+    from heat_tpu.parallel.mesh import build_mesh
+    from heat_tpu.serve.engine import MegaLaneEngine
+
+    cfg = ALIGNED_MEGA
+    mesh = build_mesh(2, (2, 2), devices=jax.devices()[:4])
+    eng = MegaLaneEngine(cfg, mesh, chunk=8)
+    logical, carried = carried_shapes(cfg, (2, 2), eng.kf)
+    assert carried != logical
+    clean = MegaLaneEngine.extract(eng.final_snapshot())
+    eng.perturb_center(0.5)
+    bumped = MegaLaneEngine.extract(eng.final_snapshot())
+    diff = bumped != clean
+    assert diff.sum() == 1 and diff[32, 32]
+    assert bumped[32, 32] == np.float32(clean[32, 32] + np.float32(0.5))
+    eng.poison_center()
+    field = MegaLaneEngine.extract(eng.final_snapshot())
+    assert np.isnan(field).sum() == 1 and np.isnan(field[32, 32])
+    assert int(np.asarray(eng.dispatch_chunk(8))[1, 0]) == 0
+
+
+def test_mega_lane_nan_quarantines_the_aligned_carry(tmp_path):
+    """lane-nan on a mega request whose shards ride in the aligned carry
+    (the auto 4x2 mesh) is seen and quarantined like the logical carry's."""
+    from heat_tpu.backends.sharded import carried_shapes, fuse_depth_sharded
+
+    kf = fuse_depth_sharded(ALIGNED_MEGA, (4, 2))
+    logical, carried = carried_shapes(ALIGNED_MEGA, (4, 2), kf)
+    assert carried != logical
+    out = tmp_path / "aligned"
+    eng = Engine(quiet(out_dir=str(out), inject="lane-nan@10:req=boom"))
+    big = eng.submit(ALIGNED_MEGA, request_id="boom")
+    recs = {r["id"]: r for r in eng.results()}
+    assert recs[big]["status"] == "nonfinite", recs[big]
+    assert "mega lane" in recs[big]["error"]
+    assert not (out / "boom.npz").exists()
+    assert eng.lanes_quarantined == 1
+
+
 def test_mega_deadline_preempts_at_boundary(monkeypatch):
     """A mega request past its budget is preempted at its next chunk
     boundary (status deadline, partial usage billed) and the freed slot

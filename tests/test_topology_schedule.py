@@ -8,7 +8,6 @@ than an on-hardware observation. Skips cleanly where no TPU AOT compiler
 is available."""
 
 import jax
-import jax.numpy as jnp
 import pytest
 
 # Topology-AOT executables can't round-trip the persistent compile cache
@@ -24,7 +23,8 @@ pytestmark = pytest.mark.filterwarnings(
     "ignore:Error (reading|writing) persistent compilation cache entry"
     ":UserWarning")
 
-from heat_tpu.backends.sharded import make_padded_carry_machinery  # noqa: E402
+from heat_tpu.backends.sharded import (  # noqa: E402
+    _probe_state_struct, make_padded_carry_machinery)
 from heat_tpu.config import HeatConfig
 from heat_tpu.ops.pallas_stencil import force_compiled_kernels
 
@@ -55,17 +55,15 @@ def _no_x64():
 
 
 def _compiled_text(topo_mesh, exchange: str) -> str:
-    from jax.sharding import NamedSharding, PartitionSpec as P
-
     n, fuse, steps = 256, 4, 8
     cfg = HeatConfig(n=n, ntime=steps, dtype="float32", backend="sharded",
                      mesh_shape=(2, 2), fuse_steps=fuse, exchange=exchange,
                      local_kernel="pallas")
     with force_compiled_kernels():
         _, advance, _ = make_padded_carry_machinery(cfg, topo_mesh)
-        struct = jax.ShapeDtypeStruct(
-            (n + 4 * fuse, n + 4 * fuse), jnp.float32,
-            sharding=NamedSharding(topo_mesh, P("x", "y")))
+        # the carried state: the padded shards, in the kernel's aligned
+        # shape where each steps with the thin-band kernel (indep)
+        struct = _probe_state_struct(cfg, topo_mesh, fuse, padded=True)
         return advance.lower(struct, steps).compile().as_text()
 
 
