@@ -7,7 +7,7 @@ SHELL := /bin/bash
 
 .PHONY: test tier1 chaos race lint check audit bench bench-all bench-smoke chip-check \
         weak-scaling collective-overhead exchange-lab sharded3d-check \
-        overlap-ab compile-bisect topology-schedule topology-validate \
+        compile-bisect topology-schedule topology-validate \
         serve-lab serve-chaos-lab frontend-lab trace-lab prof-lab \
         numerics-lab steady-lab lane-lab mega-lab resume-lab fleet-lab \
         resilience-lab cache-lab perfcheck native run viz clean smoke
@@ -91,14 +91,11 @@ exchange-lab:          # where does the per-exchange cost go (HLO census)
 sharded3d-check:       # 512^3 sharded fuse-depth no-regression
 	$(PY) benchmarks/sharded3d_check.py
 
-overlap-ab:            # exchange=overlap vs indep on chip
-	$(PY) benchmarks/overlap_ab.py
-
 compile-bisect:        # fuse-depth compile-time curve (on chip)
 	$(PY) benchmarks/compile_bisect.py
 
 # the chipless labs: AOT topology compile, no chip involved
-topology-schedule:     # multi-chip schedule census (overlap evidence)
+topology-schedule:     # multi-chip schedule census of the sharded advance
 	$(PY) benchmarks/topology_schedule.py
 
 topology-validate:     # cross-chip machine-model compile validation

@@ -41,9 +41,8 @@ def deep_fuse_proven(k: int = 32, budget_s: float = 1500) -> bool:
     (compile_bisect_topology.json, local_kernel pinned to pallas):
     16384-local k=8/16/32 cold-compile in 393/980/665 s — minutes,
     bounded, inside the 1500 s default — while the 8192-local thin-band
-    k=32 family is a genuine >36-min wedge. The ONE gate the chip labs
-    (collective_overhead, overlap_ab) consult before queueing deep-fuse
-    rows."""
+    k=32 family is a genuine >36-min wedge. The gate the chip lab
+    (collective_overhead) consults before queueing deep-fuse rows."""
     here = Path(__file__).parent
     for fname in ("compile_bisect.json", "compile_bisect_topology.json"):
         try:

@@ -24,8 +24,8 @@ Run on chip: ``python benchmarks/exchange_lab.py [n]``; CPU smoke:
 benchmarks/exchange_lab.json (atomic, incremental).
 
 Findings so far (CPU census, 4x2 virtual mesh): the sequential exchange
-costs the compiled advance 3 copies/iteration (2 full-local-shard);
-``exchange="indep"`` removes one full-shape copy. The remaining
+cost the compiled advance 3 copies/iteration (2 full-local-shard); the
+indep exchange, now the only one, removes one full-shape copy. The remaining
 full-shard copy is NOT exchange-related — a control with a pure
 stencil loop body (no exchange at all) shows the identical census, so
 it belongs to the fori_loop carry structure itself and no exchange
@@ -69,7 +69,7 @@ def _census(compiled) -> dict:
     }
 
 
-# NOTE: overlap *schedule* evidence (kernels inside the async
+# NOTE: *schedule* evidence (kernels inside the async
 # collective-permute flight window) is NOT measurable here — this lab's
 # real-advance rows run the 1x1 mesh, where ppermute degenerates and the
 # compiled module has zero collective-permute pairs. The multi-chip
@@ -179,41 +179,29 @@ def main():
     from heat_tpu.parallel.mesh import build_mesh
 
     steps = 64
-    for exchange in ("seq", "indep", "overlap"):
-        for kf in (1, 8):
-            if exchange == "overlap" and kf == 1:
-                continue  # w=1 rim IS the shard edge; nothing to overlap
-            # overlap requires the Pallas kernel; pin it for the other
-            # modes too when comparing against overlap rows on TPU (on
-            # CPU smoke the seq/indep rows keep the default XLA local
-            # kernel — their censuses are the round-3 baseline)
-            lk = "pallas" if exchange == "overlap" else "auto"
-            cfg = HeatConfig(n=n, ntime=steps, dtype="float32",
-                             backend="sharded", mesh_shape=(1, 1),
-                             fuse_steps=kf, exchange=exchange,
-                             local_kernel=lk)
-            hmesh = build_mesh(cfg.ndim, cfg.mesh_shape)
-            seed, advance, crop = make_padded_carry_machinery(cfg, hmesh)
-            Tp = seed(jnp.zeros((n, n), jnp.float32))
-            compiled = advance.lower(Tp, steps).compile()
-            census = _census(compiled)
-            # the advance donates its carry, so two_point recycles buffers
-            # static step-count arg is baked into the executable; Tp is
-            # donated into the measurement (lowering didn't consume it) —
-            # a second seeded buffer would double resident padded state
-            rate, _ = two_point_rate(compiled, Tp, n * n * steps,
-                                     repeats=3)
-            del Tp
-            per_step = n * n / rate if rate else None
-            key = f"real_advance_{exchange}_fuse{kf}"
-            rec["variants"][key] = {"hlo": census,
-                                    "per_step_s": per_step}
-            per_step_us = (f"{per_step * 1e6:9.1f} us"
-                           if per_step is not None else "      n/a")
-            print(f"real advance {exchange} fuse={kf}: "
-                  f"per-step {per_step_us}  hlo={census}",
-                  flush=True)
-            write_atomic(out, rec)
+    for kf in (1, 8):
+        cfg = HeatConfig(n=n, ntime=steps, dtype="float32",
+                         backend="sharded", mesh_shape=(1, 1), fuse_steps=kf)
+        hmesh = build_mesh(cfg.ndim, cfg.mesh_shape)
+        seed, advance, crop = make_padded_carry_machinery(cfg, hmesh)
+        Tp = seed(jnp.zeros((n, n), jnp.float32))
+        compiled = advance.lower(Tp, steps).compile()
+        census = _census(compiled)
+        # the advance donates its carry, so two_point recycles buffers
+        # static step-count arg is baked into the executable; Tp is
+        # donated into the measurement (lowering didn't consume it) —
+        # a second seeded buffer would double resident padded state
+        rate, _ = two_point_rate(compiled, Tp, n * n * steps, repeats=3)
+        del Tp
+        per_step = n * n / rate if rate else None
+        # keyed by the exchange's name, as in the older records
+        rec["variants"][f"real_advance_indep_fuse{kf}"] = {
+            "hlo": census, "per_step_s": per_step}
+        per_step_us = (f"{per_step * 1e6:9.1f} us"
+                       if per_step is not None else "      n/a")
+        print(f"real advance fuse={kf}: per-step {per_step_us}  "
+              f"hlo={census}", flush=True)
+        write_atomic(out, rec)
     print(f"wrote {out}")
 
 

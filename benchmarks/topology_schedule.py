@@ -1,12 +1,11 @@
-"""Multi-chip schedule evidence via AOT topology compile — no chip needed.
+"""Multi-chip schedule census via AOT topology compile — no chip needed.
 
-The overlap exchange (backends/sharded.py::padded_multi_overlap) claims
-XLA's latency-hiding scheduler will fly the halo collectives behind the
-interior kernel. A 1x1 mesh can't show that (ppermute degenerates), and
-multi-chip hardware isn't attached — but ``jax.experimental.topologies``
-compiles a GENUINE multi-chip TPU executable on a CPU-only host (the
-Mosaic + XLA:TPU compilers ship in libtpu and need no device), so the
-claim is checkable from the compiled module's schedule order:
+A 1x1 mesh can't show where the sharded advance's halo collectives sit
+in the schedule (ppermute degenerates), and multi-chip hardware isn't
+attached — but ``jax.experimental.topologies`` compiles a GENUINE
+multi-chip TPU executable on a CPU-only host (the Mosaic + XLA:TPU
+compilers ship in libtpu and need no device), so the schedule is
+readable from the compiled module's order:
 
 - async ``collective-permute-start``/``-done`` pairs (TPU lowering of the
   ppermutes), and
@@ -15,12 +14,12 @@ claim is checkable from the compiled module's schedule order:
 
 Compiled-module text is in schedule order for TPU, so "inside the
 window" is the scheduler's actual decision, not an inference. Measured
-first run (v5e:2x4, 4x2 mesh, 1024^2, fuse 4):
-``indep``: 1 kernel call, 0 in-window (strictly exchange-then-kernel);
-``overlap``: 5 kernel calls (interior + 4 rim bands), interior IN-window.
+first run (v5e:2x4, 4x2 mesh, 1024^2, fuse 4): 1 kernel call, 0
+in-window (strictly exchange-then-kernel).
 
 Run anywhere, no chip needed: ``python benchmarks/topology_schedule.py``
-Writes benchmarks/topology_schedule.json (atomic, incremental).
+Writes benchmarks/topology_schedule.json (atomic); its one row is
+``rows["indep"]``, the exchange's name in the older records.
 """
 
 from __future__ import annotations
@@ -105,7 +104,6 @@ def main():
     ap.add_argument("--out", default="topology_schedule.json",
                     help="output filename (under benchmarks/) — flagship-"
                          "shape runs must not clobber the toy-scale row")
-    ap.add_argument("--exchanges", default="seq,indep,overlap")
     args = ap.parse_args()
 
     import jax
@@ -138,36 +136,26 @@ def main():
            "steps": args.steps, "rows": {}}
 
     with force_compiled_kernels():
-        for ex in args.exchanges.split(","):
-            # ndim follows the mesh rank (a 2x2x2 --mesh censuses the 3D
-            # 26-region narrow overlap, 6 flight windows)
-            cfg = HeatConfig(n=args.n, ndim=len(mesh_shape),
-                             ntime=args.steps, dtype="float32",
-                             backend="sharded", mesh_shape=mesh_shape,
-                             fuse_steps=args.fuse, exchange=ex,
-                             local_kernel="pallas")
-            _, advance, _ = make_padded_carry_machinery(cfg, mesh)
-            struct = _probe_state_struct(
-                cfg, mesh, fuse_depth_sharded(cfg, mesh_shape), padded=True)
-            t0 = time.perf_counter()
-            try:
-                txt = advance.lower(struct, args.steps).compile().as_text()
-            except Exception as e:  # record, keep going
-                rec["rows"][ex] = {"error": f"{type(e).__name__}: {e}"}
-                print(f"{ex:8s} FAILED: {type(e).__name__}: "
-                      f"{str(e)[:160]}", flush=True)
-                write_atomic(out, rec)
-                continue
-            row = schedule_census(txt)
-            row["compile_s"] = time.perf_counter() - t0
-            rec["rows"][ex] = row
-            print(f"{ex:8s} pairs={row['async_pairs']} "
-                  f"kernels={row['custom_calls']} "
-                  f"in-flight={row['kernels_in_flight']} "
-                  f"(per-window {row['kernels_in_flight_per_window']}) "
-                  f"copies={row['copies']} "
-                  f"[compile {row['compile_s']:.0f}s]", flush=True)
-            write_atomic(out, rec)
+        # ndim follows the mesh rank (a 2x2x2 --mesh censuses the 3D
+        # advance)
+        cfg = HeatConfig(n=args.n, ndim=len(mesh_shape), ntime=args.steps,
+                         dtype="float32", backend="sharded",
+                         mesh_shape=mesh_shape, fuse_steps=args.fuse,
+                         local_kernel="pallas")
+        _, advance, _ = make_padded_carry_machinery(cfg, mesh)
+        struct = _probe_state_struct(
+            cfg, mesh, fuse_depth_sharded(cfg, mesh_shape), padded=True)
+        t0 = time.perf_counter()
+        txt = advance.lower(struct, args.steps).compile().as_text()
+        row = schedule_census(txt)
+        row["compile_s"] = time.perf_counter() - t0
+        rec["rows"]["indep"] = row
+        print(f"pairs={row['async_pairs']} kernels={row['custom_calls']} "
+              f"in-flight={row['kernels_in_flight']} "
+              f"(per-window {row['kernels_in_flight_per_window']}) "
+              f"copies={row['copies']} "
+              f"[compile {row['compile_s']:.0f}s]", flush=True)
+        write_atomic(out, rec)
     print(f"wrote {out}")
 
 

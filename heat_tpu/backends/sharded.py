@@ -34,7 +34,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import itertools
 import os
 import threading
 import time
@@ -47,13 +46,11 @@ from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..config import HeatConfig
-from ..ops.pallas_stencil import (_KMAX_2D, _NO_FREEZE,
-                                  ftcs_multistep_bounded_pallas,
+from ..ops.pallas_stencil import (_KMAX_2D, ftcs_multistep_bounded_pallas,
                                   multistep_points, pallas_available,
                                   thin_carry_shape)
 from ..ops.stencil import accum_dtype_for, laplacian_interior
-from ..parallel.halo import (exchange_bytes, halo_exchange,
-                             halo_exchange_indep, halo_pad, halo_recvs)
+from ..parallel.halo import exchange_bytes, halo_exchange_indep, halo_pad
 from ..parallel.mesh import build_mesh, validate_divisible
 from ..runtime.logging import master_print
 from ..utils import jnp_dtype
@@ -91,16 +88,6 @@ def make_local_multistep(cfg: HeatConfig, axis_names, axis_sizes):
     n = cfg.n
 
     use_pallas = _local_kernel_is_pallas(cfg)
-    overlap = cfg.exchange == "overlap"
-    if overlap and not use_pallas:
-        raise ValueError(
-            "exchange='overlap' requires the Pallas local kernel (the "
-            "interior/rim split is built on the bounded multistep kernel); "
-            "use local_kernel='pallas', or exchange='indep'")
-    # overlap uses the indep ghost writes for the exchange itself (fewest
-    # full-shard copies, bit-identical — tests/test_sharded.py)
-    exchange_fn = (halo_exchange_indep if cfg.exchange in ("indep", "overlap")
-                   else halo_exchange)
 
     def _shard_bounds(padded_shape, wpad: int) -> list:
         """Per-axis [lo, hi] freeze bounds in PADDED shard coordinates:
@@ -135,7 +122,7 @@ def make_local_multistep(cfg: HeatConfig, axis_names, axis_sizes):
         exchange and the freeze bounds then keep to the logical extent,
         and the kernel steps the aligned array with no pad or slice."""
         logical = tuple(n // s + 2 * wpad for s in axis_sizes)
-        padded0 = exchange_fn(
+        padded0 = halo_exchange_indep(
             padded, axis_names, axis_sizes, bc_value,
             staged=staged, width=wpad, periodic=periodic, logical=logical,
         )
@@ -185,198 +172,6 @@ def make_local_multistep(cfg: HeatConfig, axis_names, axis_sizes):
             p = mini_step(p)
         return p
 
-    def _set(out, src, dst_sl, src_sl):
-        # all slicing is static; skip degenerate spans (tiny shards).
-        # Shared by both overlap formulations below.
-        if any(s.stop <= s.start for s in dst_sl):
-            return out
-        return out.at[tuple(dst_sl)].set(src[tuple(src_sl)])
-
-    def padded_multi_overlap(padded: jax.Array, wpad: int,
-                             ksteps: int) -> jax.Array:
-        """``padded_multi`` restructured so the halo exchange can fly
-        while the interior computes (SURVEY.md §7's "hard part"; VERDICT
-        r3 #5). Same contract, bit-identical owned values (pinned by
-        tests/test_overlap.py and dryrun sub-check #12).
-
-        The sequential form is exchange -> kernel: every cell waits on the
-        collectives. Here the fused block splits three ways:
-
-        1. **Interior** (owned cells >= wpad from the shard edge): their
-           ksteps<=wpad dependency cone reads only initial values of cells
-           at distance >= 0 — by the margin argument a cell at distance s
-           contributes only its step-(k-s) value, so distance-wpad cells
-           contribute initial values only and NO fresh ghost (and no
-           freeze mask) is ever consulted. Computed from the PRE-exchange
-           field: zero data dependency on the collectives, so XLA's
-           latency-hiding scheduler is free to hoist the ppermute starts
-           before it and sink the dones after it.
-        2. **Exchange**: the indep RECEIVES (halo_recvs) kept as separate
-           per-face slabs — never written into one array on this path. A
-           rim kernel slicing the fully-written array would depend on
-           EVERY collective; round 4 shipped exactly that and the
-           flagship schedule census showed the cost: 1 kernel in flight
-           of 7, 3 of 4 windows empty
-           (benchmarks/topology_schedule_flagship_f16.json).
-        3. **Boundary regions** (round 5, the narrow-dependency rework):
-           the owned rim splits into the 3^nd - 1 regions of cells within
-           wpad of each face subset. A FACE region's input is assembled
-           from pre-exchange data + that one axis's ghost slab only, so
-           its only wire dependency is its own axis's ppermutes — the
-           scheduler can run it inside the other axes' flight windows
-           (the recvs chain is sequential by axis: axis d's sends stitch
-           e<d's fresh corners). Edge/corner regions (3*wpad per nonzero
-           axis) depend on exactly their axes' slabs and are tiny.
-           Band-edge garbage travels one cell per mini-step and never
-           reaches a kept cell (distance >= wpad >= ksteps), the same
-           invariant as the exchange itself. Tiny shards (local < 2*wpad,
-           where a 3*wpad input would cross into the far ghost margin)
-           take the round-4 wide formulation (_overlap_wide) instead.
-
-        Extra compute vs the fused form: the regions re-cover ~8*wpad/L
-        of the block (1.6% at 16384^2, wpad=32) plus the extra kernel
-        launches per block; the win is the exchange latency hidden behind
-        the interior and prior-axis face passes. Kept-region writes are
-        disjoint and complete by construction (each owned cell's region
-        is determined by its per-axis rim membership)."""
-        w = wpad
-        nd = padded.ndim
-        Lp = padded.shape
-
-        # 1) interior, from the PRE-exchange field
-        owned = padded[tuple(slice(w, -w) for _ in range(nd))]
-        nofreeze = jnp.asarray([-_NO_FREEZE, _NO_FREEZE] * nd, jnp.int32)
-        interior = ftcs_multistep_bounded_pallas(owned, r, ksteps, nofreeze)
-        bounds = _shard_bounds(Lp, w)
-
-        if any(Lp[d] - 2 * w < 2 * w for d in range(nd)):
-            # tiny shard (local < 2w): the narrow-dep region inputs below
-            # would reach into the FAR ghost margin (garbage inside the
-            # kept cone) — use the wide formulation: exchange fully, rim
-            # bands slice the written array
-            return _overlap_wide(padded, interior, bounds, w, ksteps)
-
-        # 2) per-face receive slabs — NOT written into one array: a rim
-        # kernel that slices the fully-written array depends on EVERY
-        # collective and can never enter a flight window (round-4 census:
-        # 1 kernel in flight of 7, 3 of 4 windows empty —
-        # topology_schedule_flagship_f16.json). Each region below is
-        # assembled from only the slabs its kept cells read, so a face
-        # band's only wire dependency is its OWN axis's ppermutes and the
-        # scheduler is free to run it inside other axes' windows.
-        recvs = halo_recvs(padded, axis_names, axis_sizes, bc_value,
-                           staged=staged, width=w, periodic=periodic)
-
-        def region_input(sigma):
-            """Region ``sigma`` in {-1,0,+1}^nd: cells within w of the
-            faces sigma marks. Input = pre-exchange data + ONLY those
-            faces' fresh ghost slabs, overwritten in increasing axis
-            order (same last-write-wins corner ownership as
-            apply_recvs)."""
-            src = []
-            for d, s in enumerate(sigma):
-                src.append(slice(w, Lp[d] - w) if s == 0
-                           else slice(0, 3 * w) if s < 0
-                           else slice(Lp[d] - 3 * w, Lp[d]))
-            I = padded[tuple(src)]
-            for d, s in enumerate(sigma):
-                if s == 0:
-                    continue
-                slab = recvs[d][0 if s < 0 else 1]
-                g_sl = []
-                for e, se in enumerate(sigma):
-                    if e == d:
-                        g_sl.append(slice(None))  # slab is w deep on d
-                    elif se == 0:
-                        g_sl.append(slice(w, Lp[e] - w))
-                    elif se < 0:
-                        g_sl.append(slice(0, 3 * w))
-                    else:
-                        g_sl.append(slice(Lp[e] - 3 * w, Lp[e]))
-                dst = [slice(None)] * nd
-                dst[d] = slice(0, w) if s < 0 else slice(2 * w, 3 * w)
-                I = I.at[tuple(dst)].set(slab[tuple(g_sl)])
-            return I
-
-        # output bases on the PRE-exchange array: every owned cell is
-        # overwritten below, and the ghost margins are garbage by contract
-        # (the next exchange rewrites every margin cell before any read)
-        out = padded
-        # interior kept: owned cells at distance >= w (padded [2w, Lp-2w))
-        out = _set(out, interior,
-                   [slice(2 * w, Lp[d] - 2 * w) for d in range(nd)],
-                   [slice(w, Lp[d] - 3 * w) for d in range(nd)])
-        # 3) all 3^nd - 1 boundary regions: faces (one nonzero — depend on
-        # one axis's wire only), then edges/corners (tiny, multi-axis)
-        for sigma in itertools.product((-1, 0, 1), repeat=nd):
-            if not any(sigma):
-                continue
-            off = [0 if s < 0 else Lp[d] - 3 * w if s > 0 else w
-                   for d, s in enumerate(sigma)]
-            bnd = list(bounds)
-            for d in range(nd):
-                bnd[2 * d] = bnd[2 * d] - off[d]
-                bnd[2 * d + 1] = bnd[2 * d + 1] - off[d]
-            band = ftcs_multistep_bounded_pallas(
-                region_input(sigma), r, ksteps,
-                jnp.stack(bnd).astype(jnp.int32))
-            sl_keep, sl_dst = [], []
-            for d, s in enumerate(sigma):
-                if s == 0:  # clear of every face of this axis
-                    sl_keep.append(slice(w, Lp[d] - 3 * w))
-                    sl_dst.append(slice(2 * w, Lp[d] - 2 * w))
-                else:       # the w-deep owned rim of this face
-                    sl_keep.append(slice(w, 2 * w))
-                    sl_dst.append(slice(w, 2 * w) if s < 0
-                                  else slice(Lp[d] - 2 * w, Lp[d] - w))
-            out = _set(out, band, sl_dst, sl_keep)
-        return out
-
-    def _overlap_wide(padded, interior, bounds, w, ksteps):
-        """Round-4 overlap shape for tiny shards: full exchange, rim
-        bands slice the written array (every band waits on all wires —
-        immaterial at sizes where bands ARE most of the shard)."""
-        nd = padded.ndim
-        Lp = padded.shape
-
-        padded0 = exchange_fn(
-            padded, axis_names, axis_sizes, bc_value,
-            staged=staged, width=w, periodic=periodic,
-        )
-        out = padded0
-        out = _set(out, interior,
-                   [slice(2 * w, Lp[d] - 2 * w) for d in range(nd)],
-                   [slice(w, Lp[d] - 3 * w) for d in range(nd)])
-        for d in range(nd):
-            for lo in (True, False):
-                off = 0 if lo else Lp[d] - 3 * w
-                sl_in = [slice(None)] * nd
-                sl_in[d] = slice(off, off + 3 * w)
-                bnd = list(bounds)
-                bnd[2 * d] = bnd[2 * d] - off
-                bnd[2 * d + 1] = bnd[2 * d + 1] - off
-                band = ftcs_multistep_bounded_pallas(
-                    padded0[tuple(sl_in)], r, ksteps,
-                    jnp.stack(bnd).astype(jnp.int32))
-                sl_keep = [slice(None)] * nd
-                sl_dst = [slice(None)] * nd
-                for e in range(nd):
-                    if e == d:  # this face's w-deep owned rim
-                        sl_keep[e] = slice(w, 2 * w)
-                        sl_dst[e] = (slice(w, 2 * w) if lo
-                                     else slice(Lp[d] - 2 * w, Lp[d] - w))
-                    elif e < d:  # earlier axes' bands own the corners
-                        sl_keep[e] = slice(2 * w, Lp[e] - 2 * w)
-                        sl_dst[e] = sl_keep[e]
-                    else:  # later axes: full owned span (incl. corners)
-                        sl_keep[e] = slice(w, Lp[e] - w)
-                        sl_dst[e] = sl_keep[e]
-                out = _set(out, band, sl_dst, sl_keep)
-        return out
-
-    if overlap:
-        padded_multi = padded_multi_overlap
-
     def local_multi(local: jax.Array, w: int) -> jax.Array:
         out = padded_multi(halo_pad(local, bc_value, w), w, w)
         ctr = tuple(slice(w, -w) for _ in range(out.ndim))
@@ -416,11 +211,6 @@ def make_parity_machinery(cfg: HeatConfig, mesh):
     staged = cfg.comm == "staged"
     periodic = cfg.bc == "periodic"
     n = cfg.n
-    # bit-identical formulations (tests/test_sharded.py pins it), so the
-    # literal update-then-swap ordering is preserved either way; "overlap"
-    # has no meaning at w=1 parity stepping — it gets indep's exchange
-    exchange_fn = (halo_exchange_indep if cfg.exchange in ("indep", "overlap")
-                   else halo_exchange)
     spec = P(*axis_names)
     smap = functools.partial(shard_map, mesh=mesh, in_specs=(spec,),
                              out_specs=spec, check_vma=False)
@@ -452,16 +242,16 @@ def make_parity_machinery(cfg: HeatConfig, mesh):
         new = jnp.where(_pinned_mask(padded), padded,
                         new.astype(padded.dtype))
         # ghost update AFTER the stencil — the literal :218 ``call swap()``
-        return exchange_fn(new, axis_names, axis_sizes, bc_value,
-                           staged=staged, width=1, periodic=periodic)
+        return halo_exchange_indep(new, axis_names, axis_sizes, bc_value,
+                                   staged=staged, width=1, periodic=periodic)
 
     def seed(T_owned: jax.Array, from_ic: bool) -> jax.Array:
         def body(local):
             padded = halo_pad(local, bc_value, 1)
             if from_ic:
-                padded = exchange_fn(padded, axis_names, axis_sizes,
-                                     bc_value, staged=staged, width=1,
-                                     periodic=periodic)
+                padded = halo_exchange_indep(padded, axis_names, axis_sizes,
+                                             bc_value, staged=staged, width=1,
+                                             periodic=periodic)
             return padded
 
         return jax.jit(smap(body))(T_owned)
@@ -533,11 +323,12 @@ _SAFE_FUSE = 16
 # Default probe wall budget. Sized ABOVE every measured cold compile of a
 # program the auto planner can pick — the guard exists to catch the
 # genuinely wedged family (thin-band deep unroll: >36 min before being
-# killed), not to time out legitimate flagship compiles. Measured ceiling:
-# the 16384^2 overlap flagship cold-compiles in 1833 s
-# (benchmarks/overlap_compile_check.json) — which EXCEEDED the previous
-# 1800 s default, so a cold-cache `--exchange overlap` run used to default
-# into the fallback (VERDICT r4 weak #1). 2400 s clears it with margin.
+# killed), not to time out legitimate flagship compiles. Slowest measured
+# compiles of programs still built, all at flagship scale (16384-local,
+# Pallas): depth 8 in ~6-16 min (compile_bisect_topology.json), depth 16
+# 471 s live and up to 2038 s on the host-contended chipless topology
+# path (benchmarks/kernel_census.py). No measured compile now needs
+# 2400 s; the value stays until a measured compile retunes it.
 _DEFAULT_BUDGET_S = "2400"
 
 
@@ -655,8 +446,8 @@ def _guard_fuse_compile(cfg: HeatConfig, mesh, remaining: int,
     exceeds the measured-safe depth, every program drive() will compile is
     compiled under one wall budget (``HEAT_COMPILE_BUDGET_S``, default
     ``_DEFAULT_BUDGET_S`` = 2400 s — sized above the slowest measured
-    legitimate cold compile, the 1833 s overlap flagship; 0 disables); on
-    timeout the solve falls back to the
+    legitimate cold compile; 0 disables); on timeout the solve falls back
+    to the
     seconds-compiling XLA local kernel with a loud warning, job-wide
     (_agree_any_timeout), and the abandoned Mosaic compile finishes into
     the persistent cache (a rerun gets the kernel for free if it does
@@ -750,18 +541,6 @@ def _guard_fuse_compile(cfg: HeatConfig, mesh, remaining: int,
     # cadence/ghost widths any telemetry shows — would not match the
     # program that runs (review r5).
     degrade = {"local_kernel": "xla", "fuse_steps": kf}
-    note = ""
-    if cfg.exchange == "overlap":
-        # overlap is BUILT on the Pallas bounded-multistep kernel
-        # (make_local_multistep raises for overlap-without-Pallas), so the
-        # exchange must degrade with the kernel — the guard's whole point
-        # is that a default run never crashes or stalls unboundedly. indep
-        # is bit-identical on owned cells (tests/test_sharded.py), only
-        # the interior/rim latency-hiding split is lost.
-        degrade["exchange"] = "indep"
-        note = (" exchange='overlap' needs that kernel, so the exchange "
-                "falls back to 'indep' as well (owned values bit-identical; "
-                "only the latency-hiding split is lost).")
     if rep.orphan == "left_running":
         orphan_note = (
             "The abandoned Mosaic compile continues and lands in the "
@@ -780,7 +559,7 @@ def _guard_fuse_compile(cfg: HeatConfig, mesh, remaining: int,
         f"WARNING: auto fuse depth {kf} (Pallas kernel) did not compile "
         f"within {rep.budget_s:.0f}s (HEAT_COMPILE_BUDGET_S); falling back "
         f"to local_kernel='xla' at the same fuse depth — compiles in "
-        f"seconds, ~5x lower per-step throughput.{note} {orphan_note} "
+        f"seconds, ~5x lower per-step throughput. {orphan_note} "
         f"Pass --local-kernel pallas to wait the compile out.")
     rep.degraded = degrade
     rep.probe_s = time.perf_counter() - t0
@@ -940,10 +719,9 @@ def carried_shapes(cfg: HeatConfig, axis_sizes, kf: int) -> tuple:
     thin-band kernel's aligned shape where each shard steps with that
     kernel (``thin_carry_shape``), so a fused block runs no pad or slice
     of the whole shard; the logical shape everywhere else (the XLA local
-    kernel, the col-tiled plan, 3D, the overlap exchange)."""
+    kernel, the col-tiled plan, 3D)."""
     logical = tuple(cfg.n // int(s) + 2 * kf for s in axis_sizes)
-    if (cfg.ndim == 2 and cfg.exchange != "overlap"
-            and _local_kernel_is_pallas(cfg)):
+    if cfg.ndim == 2 and _local_kernel_is_pallas(cfg):
         return logical, thin_carry_shape(logical, cfg.dtype, kf) or logical
     return logical, logical
 
@@ -1024,15 +802,12 @@ def _carried_blocks(k: int, kf: int) -> list:
 
 
 def carried_points(cfg: HeatConfig, axis_sizes, kf: int,
-                   k: int) -> Optional[int]:
+                   k: int) -> int:
     """Point-steps the local kernels of all shards compute for one
     padded-carry chunk of ``k`` steps: each block steps the whole padded
     shard (owned cells plus the kf-wide ghost margins), the Pallas kernel
     by its own plan (band halo rows and alignment padding included), the
-    XLA step cell by cell. None for the overlap exchange, whose interior
-    and rim regions are not counted."""
-    if cfg.exchange == "overlap":
-        return None
+    XLA step cell by cell."""
     # the kernel's plan is the logical shard's, whichever array carries it
     shape = carried_shapes(cfg, axis_sizes, kf)[0]
     pallas = _local_kernel_is_pallas(cfg)

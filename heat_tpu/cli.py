@@ -60,12 +60,6 @@ def build_parser() -> argparse.ArgumentParser:
     run.add_argument("--comm", choices=["direct", "staged"],
                      help="halo exchange: device-direct (CUDA-aware analog) "
                           "or host-staged (NO_AWARE analog)")
-    run.add_argument("--exchange", choices=["seq", "indep", "overlap"],
-                     help="ghost-write formulation: axes chained (seq, "
-                          "reference-like), all-independent (indep), or "
-                          "indep plus interior compute overlapped with the "
-                          "halo collectives (overlap; Pallas kernel only); "
-                          "bit-identical results")
     run.add_argument("--mesh", type=_parse_mesh,
                      help="device mesh shape, e.g. 4x2 (sharded backend)")
     run.add_argument("--virtual-devices", type=int, metavar="N",
@@ -731,7 +725,6 @@ def _apply_overrides(cfg: HeatConfig, args) -> HeatConfig:
     instead of hand-rolling a drifting copy."""
     over = {}
     for field in ("backend", "dtype", "ic", "bc", "ntime", "ndim", "comm",
-                  "exchange",
                   "fuse_steps", "local_kernel", "heartbeat_every",
                   "checkpoint_every", "checkpoint_dir", "async_io",
                   "profile_dir", "write_int", "on_nan", "inject"):

@@ -28,7 +28,6 @@ _ICS = ("hat", "hat_half", "hat_small", "uniform", "zero", "sine")
 _COMMS = ("direct", "staged")
 _ASYNC_IO = ("on", "off", "auto")
 _ON_NAN = ("abort", "rollback")
-_EXCHANGES = ("seq", "indep", "overlap")
 _LOCAL_KERNELS = ("auto", "xla", "pallas")
 
 # --serve-lane-kernel grammar (serve/scheduler.py ServeConfig.lane_kernel):
@@ -69,16 +68,6 @@ class HeatConfig:
                                 # but never enables (mpi+cuda/heat.F90:76,97)
     bc_value: float = 1.0       # boundary temperature (unused for periodic)
     comm: str = "direct"        # halo exchange: direct ICI ppermute vs host-staged
-    exchange: str = "indep"     # ghost-write formulation: "indep" (all ghost
-                                # writes independent — one fewer full-shard
-                                # copy per exchange in the compiled multi-
-                                # device advance) vs "seq" (axes chained, the
-                                # reference-like form) vs "overlap" (indep
-                                # exchange + interior compute issued while
-                                # halo slabs are in flight; Pallas local
-                                # kernel only). Bit-identical results; see
-                                # parallel/halo.py::halo_exchange_indep and
-                                # backends/sharded.py padded_multi_overlap
     local_kernel: str = "auto"  # sharded per-shard compute: auto (pallas on
                                 # TPU, xla elsewhere), or forced
     mesh_shape: Optional[Tuple[int, ...]] = None  # device mesh; None = auto
@@ -145,9 +134,6 @@ class HeatConfig:
             raise ValueError(f"ic must be one of {_ICS}, got {self.ic!r}")
         if self.comm not in _COMMS:
             raise ValueError(f"comm must be one of {_COMMS}, got {self.comm!r}")
-        if self.exchange not in _EXCHANGES:
-            raise ValueError(
-                f"exchange must be one of {_EXCHANGES}, got {self.exchange!r}")
         if self.local_kernel not in _LOCAL_KERNELS:
             raise ValueError(
                 f"local_kernel must be one of {_LOCAL_KERNELS}, got {self.local_kernel!r}")

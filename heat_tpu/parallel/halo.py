@@ -69,10 +69,10 @@ def exchange_bytes(padded_shape: Sequence[int], axis_sizes: Sequence[int],
     device to another, averaged over the mesh's devices (to the nearest
     byte). For each axis every ppermute pair between two different shards
     carries one send slab: ``width`` deep on that axis and the full padded
-    extent of the others, as ``halo_exchange`` and ``halo_recvs`` cut it.
-    An edge shard of a non-periodic axis has one such pair, an inner shard
-    two; a periodic axis of one shard sends to itself, which moves
-    nothing between devices."""
+    extent of the others, as ``halo_exchange_indep`` cuts it. An edge
+    shard of a non-periodic axis has one such pair, an inner shard two; a
+    periodic axis of one shard sends to itself, which moves nothing
+    between devices."""
     total = 0.0
     for d, size in enumerate(axis_sizes):
         slab = int(np.prod(padded_shape)) // padded_shape[d] * width
@@ -122,6 +122,11 @@ def halo_exchange(
     ``logical`` (default: the whole array) is the extent of the padded
     shard at ``padded``'s origin, where ``padded`` carries alignment cells
     beyond it: slabs are cut and written inside that extent.
+
+    No solve path calls this form: the sharded backend exchanges with
+    ``halo_exchange_indep``. It stays as the plain reference that
+    tests/test_sharded.py::test_halo_exchange_indep_bitwise holds the
+    indep form to, bit for bit.
     """
     w = width
     L = tuple(padded.shape if logical is None else logical)
@@ -154,7 +159,7 @@ def halo_exchange(
     return out
 
 
-def halo_recvs(
+def halo_exchange_indep(
     padded: jax.Array,
     axis_names: Sequence[str],
     axis_sizes: Sequence[int],
@@ -163,23 +168,26 @@ def halo_recvs(
     width: int = 1,
     periodic: bool = False,
     logical: Sequence[int] | None = None,
-) -> dict:
-    """The receive half of the indep exchange: ``{d: (from_prev,
-    from_next)}`` ghost slabs, each spanning the FULL padded extent of the
-    other axes with earlier-axis corner data stitched in.
+) -> jax.Array:
+    """``halo_exchange`` with all ghost writes made independent: the
+    sharded backend's one exchange.
 
-    Exposed separately from the writes so the overlap exchange can hand
-    each rim kernel ONLY the slab it reads — a rim band that slices the
-    fully-written array depends on every collective and cannot enter any
-    flight window (the round-4 schedule census measured exactly that:
-    1 kernel in flight out of 7, benchmarks/topology_schedule_*.json).
-
-    Dependency chain to note: axis d's SEND slabs stitch axes e<d's fresh
-    ghosts into their margins (corner forwarding), so d's ppermutes start
-    only after e<d's land — the wire windows are sequential by axis; the
-    per-face consumers this function enables are what lets kernels fill
-    the later windows. ``logical`` as in ``halo_exchange``: the slabs span
-    it, not the alignment cells beyond."""
+    The sequential formulation reads axis d's send slabs from the
+    already-ghost-updated array (that is how corner ghosts forward), so
+    each axis's update-slice depends on the previous axis's — XLA can be
+    forced to materialize the intermediate (the round-3 exchange lab
+    measured a full-padded-array copy per exchange in the compiled
+    advance). Here every send slab is cut from the ORIGINAL padded array,
+    with earlier-axis corner data stitched in from those axes' received
+    slabs (slab-sized updates, not full-array), so axis d's ppermutes
+    start only after those of axes e<d land. The 2*nd ghost writes then
+    all read from ``padded`` only, in increasing axis order (later axes
+    own the corners), so XLA is free to apply them as one in-place pass.
+    Owned values and ghost values are bit-identical to ``halo_exchange``
+    — pinned by tests/test_sharded.py::test_halo_exchange_indep_bitwise.
+    ``logical`` as in ``halo_exchange``: the slabs span it, not the
+    alignment cells beyond.
+    """
     nd = padded.ndim
     w = width
     L = tuple(padded.shape if logical is None else logical)
@@ -190,7 +198,9 @@ def halo_recvs(
         sl[d] = sl_d
         return tuple(sl)
 
-    recvs = {}  # d -> (from_prev, from_next)
+    # per axis: the received (from_prev, from_next) slabs, each spanning
+    # the padded extent of the other axes, earlier axes' corners included
+    recvs = []
     for d, (name, size) in enumerate(zip(axis_names, axis_sizes)):
         idx = lax.axis_index(name)
         send_lo = padded[_cut(padded, L, d, slice(w, 2 * w))]
@@ -219,56 +229,13 @@ def halo_recvs(
         if not periodic:
             from_prev = jnp.where(idx == 0, bc, from_prev)
             from_next = jnp.where(idx == size - 1, bc, from_next)
-        recvs[d] = (from_prev, from_next)
-    return recvs
+        recvs.append((from_prev, from_next))
 
-
-def apply_recvs(padded: jax.Array, recvs: dict, width: int = 1,
-                logical: Sequence[int] | None = None) -> jax.Array:
-    """Write the received slabs into the ghost margins (the write half of
-    the indep exchange), inside the ``logical`` extent (``halo_exchange``).
-    Write order is increasing axis — later axes own the corners — and
-    every consumer assembling band inputs from ``recvs`` directly must
-    reproduce that order (``_overlap_region_input``)."""
-    w = width
-    L = tuple(padded.shape if logical is None else logical)
     out = padded
-    for d in sorted(recvs):
-        from_prev, from_next = recvs[d]
+    for d, (from_prev, from_next) in enumerate(recvs):
         out = out.at[_cut(padded, L, d, slice(0, w))].set(from_prev)
         out = out.at[_cut(padded, L, d, slice(L[d] - w, L[d]))].set(from_next)
     return out
-
-
-def halo_exchange_indep(
-    padded: jax.Array,
-    axis_names: Sequence[str],
-    axis_sizes: Sequence[int],
-    bc_value,
-    staged: bool = False,
-    width: int = 1,
-    periodic: bool = False,
-    logical: Sequence[int] | None = None,
-) -> jax.Array:
-    """``halo_exchange`` with all ghost writes made independent.
-
-    The sequential formulation reads axis d's send slabs from the
-    already-ghost-updated array (that is how corner ghosts forward), so
-    each axis's update-slice depends on the previous axis's — XLA can be
-    forced to materialize the intermediate (the round-3 exchange lab
-    measured a full-padded-array copy per exchange in the compiled
-    advance). Here every send slab is built from the ORIGINAL padded
-    array, with earlier-axis corner data stitched in from those axes'
-    received slabs (slab-sized updates, not full-array); the final 2*nd
-    ghost writes then all read from ``padded`` only, so XLA is free to
-    apply them as one in-place pass. Owned values and ghost values are
-    bit-identical to ``halo_exchange`` — pinned by
-    tests/test_sharded.py::test_halo_exchange_indep_bitwise.
-    """
-    recvs = halo_recvs(padded, axis_names, axis_sizes, bc_value,
-                       staged=staged, width=width, periodic=periodic,
-                       logical=logical)
-    return apply_recvs(padded, recvs, width=width, logical=logical)
 
 
 def halo_pad(local: jax.Array, bc_value, width: int = 1) -> jax.Array:

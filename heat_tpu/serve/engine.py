@@ -767,11 +767,11 @@ class MegaLaneEngine:
         self._cache = compiled_cache if compiled_cache is not None else {}
         self._on_compile = on_compile
         # geometry + physics fields that select a distinct compiled
-        # program family (r folds in sigma/nu/dom_len/n; exchange and
+        # program family (r folds in sigma/nu/dom_len/n; comm and
         # local_kernel shape the shard body)
         self._ckey = ("mega", cfg.ndim, cfg.n, cfg.dtype, cfg.bc,
                       repr(cfg.bc_value), repr(float(cfg.r)),
-                      tuple(mesh.devices.shape), cfg.exchange, cfg.comm,
+                      tuple(mesh.devices.shape), cfg.comm,
                       cfg.local_kernel, cfg.fuse_steps)
         self._label = (f"mega {cfg.ndim}d n{cfg.n} {cfg.dtype} {cfg.bc} "
                        f"mesh {'x'.join(map(str, mesh.devices.shape))}")

@@ -43,6 +43,10 @@ def config_from_manifest(d: dict) -> HeatConfig:
     """Rebuild a ``HeatConfig`` from its ``dataclasses.asdict`` form
     (JSON turned the ``mesh_shape`` tuple into a list)."""
     d = dict(d)
+    # ``exchange`` (the halo exchange's formulation, all bit-identical) is
+    # gone from HeatConfig, but manifests written before still carry it.
+    # Any other unknown key still fails.
+    d.pop("exchange", None)
     if d.get("mesh_shape") is not None:
         d["mesh_shape"] = tuple(int(x) for x in d["mesh_shape"])
     return HeatConfig(**d)

@@ -148,7 +148,8 @@ def test_sharded_fused_loop_steps_the_aligned_carry(mesh_2x2, steps):
     carry: no pad into the kernel's shape, no slice of the whole shard
     back out of it, and at most one whole-shard copy a block: the
     exchange's relayout of the carry to cut its column slabs
-    (``parallel/halo.py`` ``halo_recvs``), the next copy to remove. The
+    (``parallel/halo.py`` ``halo_exchange_indep``), the next copy to
+    remove. The
     program holds at most one more outside the loop. (At 512² the shard
     is small enough to stay in the chip's fast memory, and no relayout
     shows.)"""

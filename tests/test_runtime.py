@@ -84,21 +84,28 @@ def test_two_point_rate_cancels_fixed_overhead(monkeypatch):
     fixed per-measurement overhead riding on the sync fence and compute
     time C per call, the corrected rate must be ~work/C (not work/(C+O)),
     and must fall back to the raw rate when overhead dominates (noise
-    floor) rather than report an inflated figure."""
-    import time as _time
+    floor) rather than report an inflated figure. Calls and fences
+    advance a fake clock, so the figures are exact on any host."""
+    import types
 
     from heat_tpu.runtime import timing as timing_mod
 
-    monkeypatch.setattr(timing_mod, "sync",
-                        lambda x: (_time.sleep(0.060), x)[1])
+    now = [0.0]
+
+    def spend(s):
+        now[0] += s
+
+    monkeypatch.setattr(timing_mod, "time",
+                        types.SimpleNamespace(perf_counter=lambda: now[0]))
+    monkeypatch.setattr(timing_mod, "sync", lambda x: (spend(0.060), x)[1])
 
     # compute 30 ms/call + 60 ms overhead/measurement:
-    # T1 ~ 90 ms, T2 ~ 120 ms -> corrected ~ work/30ms, raw ~ work/90ms
+    # T1 = 90 ms, T2 = 120 ms -> corrected = work/30ms, raw = work/90ms
     res = timing_mod.two_point_rate(
-        lambda x: (_time.sleep(0.030), x)[1], "x", work=1.0, repeats=2)
+        lambda x: (spend(0.030), x)[1], "x", work=1.0, repeats=2)
     corrected, raw = res
-    assert raw == pytest.approx(1.0 / 0.090, rel=0.25)
-    assert corrected == pytest.approx(1.0 / 0.030, rel=0.25)
+    assert raw == pytest.approx(1.0 / 0.090)
+    assert corrected == pytest.approx(1.0 / 0.030)
     assert res.fell_back is False
 
     # overhead-dominated (compute 1 ms vs 60 ms overhead): the noise
@@ -106,7 +113,7 @@ def test_two_point_rate_cancels_fixed_overhead(monkeypatch):
     # explicitly (calibrate refuses to trust a fallen-back HBM rate;
     # float-equality re-derivation was review-flagged as fragile)
     res2 = timing_mod.two_point_rate(
-        lambda x: (_time.sleep(0.001), x)[1], "x", work=1.0, repeats=2)
+        lambda x: (spend(0.001), x)[1], "x", work=1.0, repeats=2)
     corrected2, raw2 = res2
     assert corrected2 == raw2
     assert res2.fell_back is True

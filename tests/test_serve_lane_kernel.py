@@ -132,29 +132,52 @@ def test_pallas_npz_outputs_byte_identical_across_depths(tmp_path):
                 assert a["T"].tobytes() == b["T"].tobytes(), p.name
 
 
-def test_lane_tier_growth_under_pallas_stays_exact():
+def test_lane_tier_growth_under_pallas_stays_exact(monkeypatch):
     """Online admission outgrows the born tier: the grown Pallas group
-    transplants occupants bit-exactly (padded-slab crop + reload)."""
+    transplants occupants bit-exactly (padded-slab crop + reload). The
+    scheduler is held right after the first request fills its tier-1
+    group, and the rest queue before it goes on, so the group grows
+    whatever the host's speed."""
+    import threading
+
+    from heat_tpu.serve import scheduler as sched
+
+    fill = sched._GroupRunner._fill
+    formed, go = threading.Event(), threading.Event()
+
+    def held_fill(self):
+        fill(self)
+        if not formed.is_set() and any(o is not None for o in self.occupant):
+            assert self.lanes == 1       # the born tier
+            formed.set()
+            go.wait(60)
+
+    monkeypatch.setattr(sched._GroupRunner, "_fill", held_fill)
     results = {}
     for kernel in ("xla", "pallas"):
+        formed.clear()
+        go.clear()
         eng = Engine(quiet(lanes=4, chunk=4, buckets=(12,),
                            lane_kernel=kernel))
         eng.start()
         try:
             first = eng.submit(HeatConfig(n=10, ntime=40, dtype="float32",
                                           bc="ghost"))
-            eng.wait(first, timeout=0.05)   # let a tier-1 group form
+            assert formed.wait(60)       # a tier-1 group holds `first`
             rest = [eng.submit(HeatConfig(n=8 + (i % 3), ntime=20 + 4 * i,
                                           dtype="float32", bc="ghost",
                                           ic="hat_small"))
                     for i in range(5)]
+            go.set()
             recs = [eng.wait(rid, timeout=60)
                     for rid in [first] + rest]
         finally:
+            go.set()
             eng.shutdown(timeout=60)
         assert all(r is not None and r["status"] == "ok" for r in recs)
         results[kernel] = (eng, [eng._by_id[r["id"]].get("T")
                                  for r in recs])
+    assert results["xla"][0].lane_grows >= 1
     assert results["pallas"][0].lane_grows >= 1
     for a, b in zip(results["xla"][1], results["pallas"][1]):
         assert a is not None and b is not None

@@ -352,6 +352,43 @@ def test_fingerprint_mismatch_is_loud(tmp_path):
         resume_engine(eng, ck)
 
 
+def test_resume_manifest_with_retired_exchange_field(tmp_path):
+    """A manifest written before ``HeatConfig.exchange`` was removed
+    carries ``"exchange"`` in every entry's config. Resume drops that one
+    retired key and finishes the wave byte-identically; any other key
+    the config does not know still fails loudly."""
+    wave = WAVE[:3]
+    _, golden = run_wave(tmp_path, "golden", wave)
+    run_wave(tmp_path, "killed", wave, engine_ckpt_interval=2)
+    ck = tmp_path / "killed-ckpt"
+    kill_after(ck, 1, tmp_path / "killed")
+    path = ck / ckpt_mod.ENGINE_MANIFEST_FMT.format(gen=1)
+    man = json.loads(path.read_text())
+    rows = man["inflight"] + man["queued"]
+    assert rows, "cut must land mid-wave to prove anything"
+    for e in rows:
+        assert "exchange" not in e["cfg"]
+        e["cfg"]["exchange"] = "indep"      # the older writer's form
+    path.write_text(json.dumps(man, sort_keys=True))
+
+    eng = Engine(quiet(out_dir=str(tmp_path / "resumed"),
+                       engine_ckpt_dir=str(ck)))
+    assert resume_engine(eng, ck) == {f"r{i}" for i in range(len(wave))}
+    resumed = {r["id"]: r for r in eng.results()}
+    assert all(r["status"] == "ok" for r in resumed.values())
+    for rid in golden:
+        a = tmp_path / "golden" / f"{rid}.npz"
+        b = tmp_path / "killed" / f"{rid}.npz"
+        if not b.exists():
+            b = tmp_path / "resumed" / f"{rid}.npz"
+        assert a.read_bytes() == b.read_bytes(), rid
+
+    from heat_tpu.serve.resume import config_from_manifest
+
+    with pytest.raises(TypeError, match="no_such_field"):
+        config_from_manifest({**rows[0]["cfg"], "no_such_field": 1})
+
+
 def test_resume_empty_dir_is_loud_fresh_start(tmp_path, capsys):
     eng = Engine(quiet())
     assert resume_engine(eng, tmp_path / "nowhere") == set()
