@@ -81,13 +81,17 @@ def schedule_census(txt: str) -> dict:
             windows.append((s, i))
     customs = [i for i, ln in enumerate(lines) if "custom-call" in ln]
     per_window = [sum(1 for c in customs if s < c < d) for s, d in windows]
-    in_flight = len({c for c in customs
-                     for s, d in windows if s < c < d})
+    in_flight = {c for c in customs for s, d in windows if s < c < d}
+    # each in-flight kernel by the name its instruction carries, without
+    # the compiler's numeric suffix (``%heat_halo_cut.4`` -> heat_halo_cut)
+    names = sorted(re.sub(r"\.\d+$", "", m.group(1)) for c in in_flight
+                   if (m := re.match(r"\s*%([\w.\-]+)", lines[c])))
     return {
         "async_pairs": len(windows),
         "unmatched_dones": unmatched,
         "custom_calls": len(customs),
-        "kernels_in_flight": in_flight,
+        "kernels_in_flight": len(in_flight),
+        "kernels_in_flight_names": names,
         "kernels_in_flight_per_window": per_window,
         "copies": txt.count(" copy("),
     }

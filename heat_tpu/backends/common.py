@@ -226,7 +226,8 @@ def _drive(cfg, T_dev, advance, tracer, drv_track, start_step, to_host,
     # backend reports what its kernels compute for a k-step chunk, if it can
     points_computed = getattr(advance, "points_computed", None)
     computed_by_k: dict = {}
-    # and, on a mesh, its halo exchanges and the bytes they send
+    # and, on a mesh, its halo exchanges, the bytes they send and the
+    # exchanges whose column slabs the Pallas strip copy cuts
     halo_counts = getattr(advance, "halo_counts", None)
     halo_by_k: dict = {}
 
@@ -375,9 +376,10 @@ def _drive(cfg, T_dev, advance, tracer, drv_track, start_step, to_host,
                     if halo_counts is not None:
                         if k not in halo_by_k:
                             halo_by_k[k] = halo_counts(k)
-                        exchanges, sent = halo_by_k[k]
+                        exchanges, sent, cuts = halo_by_k[k]
                         tracer.count("heat.halo.exchanges", exchanges)
                         tracer.count("heat.halo.bytes", sent)
+                        tracer.count("heat.halo.lane_cuts", cuts)
                     if plan is not None:
                         plan.maybe_crash(step)
                         T_dev = plan.maybe_nan(step, T_dev)

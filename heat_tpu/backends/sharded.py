@@ -50,7 +50,8 @@ from ..ops.pallas_stencil import (_KMAX_2D, ftcs_multistep_bounded_pallas,
                                   multistep_points, pallas_available,
                                   thin_carry_shape)
 from ..ops.stencil import accum_dtype_for, laplacian_interior
-from ..parallel.halo import exchange_bytes, halo_exchange_indep, halo_pad
+from ..parallel.halo import (exchange_bytes, halo_exchange_indep, halo_pad,
+                             lane_cut_plan)
 from ..parallel.mesh import build_mesh, validate_divisible
 from ..runtime.logging import master_print
 from ..utils import jnp_dtype
@@ -821,16 +822,20 @@ def carried_points(cfg: HeatConfig, axis_sizes, kf: int,
 
 def carried_halo_counts(cfg: HeatConfig, axis_sizes, kf: int,
                         k: int) -> tuple:
-    """``(exchanges, bytes)`` one device runs and sends for a padded-carry
-    chunk of ``k`` steps: one kf-wide exchange a fused block, the bytes
-    averaged over the devices (``halo.exchange_bytes``), whose slabs span
-    the logical shard whichever array carries it."""
-    shape = carried_shapes(cfg, axis_sizes, kf)[0]
+    """``(exchanges, bytes, lane_cuts)`` one device runs and sends for a
+    padded-carry chunk of ``k`` steps: one kf-wide exchange a fused block,
+    the bytes averaged over the devices (``halo.exchange_bytes``), whose
+    slabs span the logical shard whichever array carries it, and the
+    exchanges whose column slabs the Pallas strip copy cuts: all of them
+    where the carried shard fits its tiles (``halo.lane_cut_plan``), else
+    none."""
+    logical, carried = carried_shapes(cfg, axis_sizes, kf)
+    dtype = jnp.dtype(jnp_dtype(cfg.dtype))
     exchanges = sum(c for _, c in _carried_blocks(k, kf))
-    per = exchange_bytes(shape, axis_sizes, kf,
-                         jnp.dtype(jnp_dtype(cfg.dtype)).itemsize,
+    per = exchange_bytes(logical, axis_sizes, kf, dtype.itemsize,
                          periodic=cfg.bc == "periodic")
-    return exchanges, exchanges * per
+    cut = lane_cut_plan(carried, dtype, kf, logical) is not None
+    return exchanges, exchanges * per, exchanges if cut else 0
 
 
 def make_mega_machinery(cfg: HeatConfig, mesh):

@@ -13,6 +13,10 @@ TPU-native replacement for the reference's swap machinery
   communicator is built to carry but never switches on (the ``pbc``
   periods argument of ``mpi_cart_create``, :97): the ppermute ring closes
   (last shard exchanges with the first) and no ghost is pinned.
+- the column send slabs of a 2-D shard whose array fits the TPU tiles
+  are cut by a small Pallas copy (``lane_cut_plan``, ``cut_lane_slabs``)
+  rather than by an XLA slice, which the compiler may serve from a
+  column-major copy of the whole shard.
 - CUDA-aware vs NO_AWARE staged duality (:162-172) -> ``staged=True`` routes
   every halo slab through a host round-trip (``jax.pure_callback``), the
   honest analog of the D2H / sendrecv-on-host / H2D path; the default sends
@@ -30,6 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental import pallas as pl
+
+from ..ops.pallas_stencil import _interpret, _sublane
 
 
 def _stage_through_host(x: jax.Array) -> jax.Array:
@@ -90,6 +97,92 @@ def _cut(padded: jax.Array, logical, d: int, sl_d: slice) -> tuple:
     return tuple(sl_d if e == d else
                  slice(None) if logical[e] == padded.shape[e] else
                  slice(0, logical[e]) for e in range(padded.ndim))
+
+
+# the lane width of a TPU tile, and the most rows one grid step of the
+# strip copy moves (a few hundred KiB a block)
+_LANES = 128
+_CUT_ROWS = 1024
+
+
+def lane_cut_plan(shape, dtype, width: int, logical=None):
+    """How ``cut_lane_slabs`` copies the column send slabs of a shard
+    (axis 1, the lane axis), or None where the shard does not fit the TPU
+    tiles and the slabs are cut by an XLA slice: ``(rows, lo, hi)``, the
+    row block and, for the low and the high slab, ``(first, count)`` of
+    the 128-lane blocks of the array that hold it. A shard fits where it
+    is 2-D in float32 or bfloat16, its rows are a multiple of the sublane
+    tile, and each slab lies inside one 128-lane block of the array or
+    two neighbouring ones. ``logical`` as in ``halo_exchange``."""
+    shape = tuple(shape)
+    if len(shape) != 2 or jnp.dtype(dtype) not in (jnp.float32,
+                                                   jnp.bfloat16):
+        return None
+    m, n = shape
+    sub = _sublane(dtype)
+    if m % sub:
+        return None
+    last = shape[1] if logical is None else logical[1]
+    blocks = []
+    for c0 in (width, last - 2 * width):
+        b0, b1 = c0 // _LANES, (c0 + width - 1) // _LANES
+        if b1 - b0 > 1 or (b1 + 1) * _LANES > n:
+            return None
+        blocks.append((b0, b1 - b0 + 1))
+    rows = max(r for r in range(sub, min(m, _CUT_ROWS) + 1, sub)
+               if m % r == 0)
+    return (rows, *blocks)
+
+
+def _make_cut_kernel(n_lo: int, n_hi: int):
+    def kernel(*refs):
+        blocks, lo_ref, hi_ref = refs[:-2], refs[-2], refs[-1]
+        for j in range(n_lo):
+            lo_ref[:, j * _LANES:(j + 1) * _LANES] = blocks[j][...]
+        for j in range(n_hi):
+            hi_ref[:, j * _LANES:(j + 1) * _LANES] = blocks[n_lo + j][...]
+
+    return kernel
+
+
+def cut_lane_slabs(padded: jax.Array, plan, width: int, logical) -> tuple:
+    """The column send slabs ``(lo, hi)`` of ``padded`` on axis 1, as
+    ``halo_exchange`` cuts them, sliced from the two strips that hold
+    them: every row, by the 128-lane blocks ``plan`` names
+    (``lane_cut_plan``), copied out by one Pallas kernel. XLA cuts a
+    narrow column slab through a column-major layout, and where the shard
+    is a loop's carried state layout assignment pushes that layout back
+    onto the whole shard: a copy of all of it, every loop iteration. A
+    custom call's operand keeps its row-major layout, so that copy stops
+    at the strips."""
+    rows, (lo0, n_lo), (hi0, n_hi) = plan
+    m = padded.shape[0]
+
+    def block(b):
+        return pl.BlockSpec((rows, _LANES), lambda i: (i, b))
+
+    def strip(count):
+        return pl.BlockSpec((rows, count * _LANES), lambda i: (i, 0))
+
+    cols = [*range(lo0, lo0 + n_lo), *range(hi0, hi0 + n_hi)]
+    lo, hi = pl.pallas_call(
+        _make_cut_kernel(n_lo, n_hi),
+        out_shape=[jax.ShapeDtypeStruct((m, c * _LANES), padded.dtype)
+                   for c in (n_lo, n_hi)],
+        grid=(m // rows,),
+        name="heat_halo_cut",
+        in_specs=[block(b) for b in cols],
+        out_specs=[strip(n_lo), strip(n_hi)],
+        cost_estimate=pl.CostEstimate(
+            flops=0, transcendentals=0,
+            bytes_accessed=2 * m * len(cols) * _LANES
+            * padded.dtype.itemsize),
+        interpret=_interpret(),
+    )(*[padded] * len(cols))
+    w, (rows_l, cols_l) = width, logical
+    c_lo = w - lo0 * _LANES
+    c_hi = cols_l - 2 * w - hi0 * _LANES
+    return lo[:rows_l, c_lo:c_lo + w], hi[:rows_l, c_hi:c_hi + w]
 
 
 def halo_exchange(
@@ -186,7 +279,10 @@ def halo_exchange_indep(
     Owned values and ghost values are bit-identical to ``halo_exchange``
     — pinned by tests/test_sharded.py::test_halo_exchange_indep_bitwise.
     ``logical`` as in ``halo_exchange``: the slabs span it, not the
-    alignment cells beyond.
+    alignment cells beyond. Where the shard fits the TPU tiles
+    (``lane_cut_plan``), the column slabs of axis 1 are cut from strips
+    that a Pallas kernel copies out (``cut_lane_slabs``); the values are
+    the same.
     """
     nd = padded.ndim
     w = width
@@ -200,11 +296,16 @@ def halo_exchange_indep(
 
     # per axis: the received (from_prev, from_next) slabs, each spanning
     # the padded extent of the other axes, earlier axes' corners included
+    plan = lane_cut_plan(padded.shape, padded.dtype, w, L)
     recvs = []
     for d, (name, size) in enumerate(zip(axis_names, axis_sizes)):
         idx = lax.axis_index(name)
-        send_lo = padded[_cut(padded, L, d, slice(w, 2 * w))]
-        send_hi = padded[_cut(padded, L, d, slice(L[d] - 2 * w, L[d] - w))]
+        if d == 1 and plan is not None:
+            send_lo, send_hi = cut_lane_slabs(padded, plan, w, L)
+        else:
+            send_lo = padded[_cut(padded, L, d, slice(w, 2 * w))]
+            send_hi = padded[_cut(padded, L, d,
+                                  slice(L[d] - 2 * w, L[d] - w))]
         # corner forwarding: overwrite the earlier-axis margins of the
         # send slab with those axes' fresh ghosts (what the sequential
         # scheme reads from the updated array)

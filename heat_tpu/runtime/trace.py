@@ -125,6 +125,8 @@ COUNTERS: Dict[str, str] = {
                            "device runs in the chunk",
     "heat.halo.bytes": "drive() on a mesh: the bytes one device sends in "
                        "those exchanges, mean of the devices",
+    "heat.halo.lane_cuts": "drive() on a mesh: those exchanges whose "
+                           "column slabs the Pallas strip copy cut",
     "heat.lane.points_useful": "lane chunk: sum over live lanes of "
                                "n^ndim x the steps each still takes",
     "heat.lane.points_computed": "lane chunk: lanes x slab points x steps",

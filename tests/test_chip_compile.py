@@ -144,15 +144,16 @@ def test_sharded_fused_loop_steps_the_aligned_carry(mesh_2x2, steps):
     at fuse depth 4 is a 1032² shard (1024 owned plus 4-wide ghosts) in a
     1280x1152 carry (the thin-band plan's 256-row tiles and 128-lane
     columns). 20 steps are five fused blocks; 23 add a remainder of 3.
-    Each loop body holds an in-place exchange and two kernel calls on the
-    carry: no pad into the kernel's shape, no slice of the whole shard
-    back out of it, and at most one whole-shard copy a block: the
-    exchange's relayout of the carry to cut its column slabs
-    (``parallel/halo.py`` ``halo_exchange_indep``), the next copy to
-    remove. The
-    program holds at most one more outside the loop. (At 512² the shard
-    is small enough to stay in the chip's fast memory, and no relayout
-    shows.)"""
+    Each loop body holds two fused blocks: two in-place exchanges and two
+    kernel calls on the carry, with no pad into the kernel's shape, no
+    slice of the whole shard back out of it, and no copy of the whole
+    shard or carry. Each exchange cuts its column send slabs from two
+    128-lane strips that the ``heat_halo_cut`` kernel copies out
+    (``parallel/halo.py`` ``cut_lane_slabs``), so layout assignment
+    finds no column-major use of the carry to relayout it for. The
+    program holds at most one whole copy, outside the loop. (At 512² the
+    shard is small enough to stay in the chip's fast memory, and no
+    relayout shows.)"""
     from heat_tpu.backends.sharded import (_probe_state_struct,
                                            make_padded_carry_machinery)
     from heat_tpu.config import HeatConfig
@@ -166,17 +167,17 @@ def test_sharded_fused_loop_steps_the_aligned_carry(mesh_2x2, steps):
     whole = ("f32[1032,1032]", "f32[1280,1152]")   # logical shard, carry
     bodies = re.findall(r"while\(.*?body=%([\w.\-]+)", hlo)
     assert bodies, "no fused loop in the program"
-    in_loop = []
     for body in bodies:
         text = comps[body]
         assert " pad(" not in text, body
         assert not [op for s in whole for op in _ops(text, "slice", s)], body
+        assert not [op for s in whole for op in _ops(text, "copy", s)], body
         kernels = re.findall(r"%heat_ftcs_2d\S* = .* custom-call\(", text)
         assert len(kernels) == 2, body
-        in_loop += [op for s in whole for op in _ops(text, "copy", s)]
-    assert len(in_loop) <= 2 * len(bodies), in_loop
+        cuts = re.findall(r"%heat_halo_cut\S* = .* custom-call\(", text)
+        assert len(cuts) == 2, body
     copies = [op for s in whole for op in _ops(hlo, "copy", s)]
-    assert len(copies) <= len(in_loop) + 1, copies
+    assert len(copies) <= 1, copies
     assert not [op for s in whole for op in _ops(hlo, "pad", s)]
 
 

@@ -75,10 +75,12 @@ def test_halo_counts_are_the_exchange_slabs(mesh_shape):
         axis = axis[0] if isinstance(axis, tuple) else axis
         crossing = sum(s != t for s, t in perm)
         per_device += crossing * int(np.prod(shape)) * item / sizes[axis]
-    assert advance.halo_counts(4) == (1, round(per_device))
+    # the XLA local kernel's 40x40 shard is narrower than a 128-lane
+    # block: no exchange cuts its column slabs with the strip copy
+    assert advance.halo_counts(4) == (1, round(per_device), 0)
     # 3 blocks of 4; 5 blocks and a rest of 3
-    assert advance.halo_counts(12) == (3, 3 * round(per_device))
-    assert advance.halo_counts(23) == (6, 6 * round(per_device))
+    assert advance.halo_counts(12) == (3, 3 * round(per_device), 0)
+    assert advance.halo_counts(23) == (6, 6 * round(per_device), 0)
 
 
 @pytest.mark.parametrize("mesh_shape", MESHES)
@@ -99,8 +101,12 @@ def test_counts_at_the_aligned_carry_are_the_logical_shards(mesh_shape):
         blocks = [(4, k // 4)] + ([(k % 4, 1)] if k % 4 else [])
         assert advance.points_computed(k) == 4 * sum(
             c * multistep_points(logical, "float32", s) for s, c in blocks)
-        assert advance.halo_counts(k) == sharded.carried_halo_counts(
-            xla, mesh_shape, 4, k)
+        exchanges, sent, cuts = advance.halo_counts(k)
+        assert (exchanges, sent) == sharded.carried_halo_counts(
+            xla, mesh_shape, 4, k)[:2]
+        # the aligned carry fits the tiles: every exchange cuts its
+        # column slabs with the strip copy
+        assert cuts == exchanges
     Tp = seed(jnp.zeros((64, 64), jnp.float32))
     assert Tp.shape == tuple(c * s for c, s in zip(carried, mesh_shape))
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -111,7 +117,7 @@ def test_counts_at_the_aligned_carry_are_the_logical_shards(mesh_shape):
         axis = axis[0] if isinstance(axis, tuple) else axis
         crossing = sum(s != t for s, t in perm)
         per_device += crossing * int(np.prod(shape)) * item / sizes[axis]
-    assert advance.halo_counts(4) == (1, round(per_device))
+    assert advance.halo_counts(4) == (1, round(per_device), 1)
 
 
 def test_halo_bytes_of_the_flagship_2x2():
@@ -120,14 +126,17 @@ def test_halo_bytes_of_the_flagship_2x2():
     cfg = HeatConfig(n=32768, ntime=1500, dtype="float32",
                      backend="sharded", bc="ghost", fuse_steps=4,
                      mesh_shape=(2, 2))
+    # each 16392² shard's last 128-lane block ends past its 16392 columns
     assert sharded.carried_halo_counts(cfg, (2, 2), 4, 1500) == (
-        375, 375 * 2 * 4 * 16392 * 4)
+        375, 375 * 2 * 4 * 16392 * 4, 0)
     # the same where each shard rides in the kernel's aligned 16464x16512
     pallas = cfg.with_(local_kernel="pallas")
     assert sharded.carried_shapes(pallas, (2, 2), 4) == (
         (16392, 16392), (16464, 16512))
+    # whose column slabs, [4, 8) and [16384, 16388), lie in the 128-lane
+    # blocks 0 and 128 of its 16512 columns: the strip copy cuts them
     assert sharded.carried_halo_counts(pallas, (2, 2), 4, 1500) == (
-        375, 375 * 2 * 4 * 16392 * 4)
+        375, 375 * 2 * 4 * 16392 * 4, 375)
 
 
 @pytest.mark.parametrize("mesh_shape", MESHES)
@@ -143,13 +152,42 @@ def test_drive_counts_the_sharded_chunks(mesh_shape):
         trace_mod.configure()
     _, (_, advance, _) = _machinery(cfg)
     # chunks of 12 and 11 steps (heartbeat interval, then the rest)
-    ex12, b12 = advance.halo_counts(12)
-    ex11, b11 = advance.halo_counts(11)
+    ex12, b12, _ = advance.halo_counts(12)
+    ex11, b11, _ = advance.halo_counts(11)
     assert c["heat.halo.exchanges"] == ex12 + ex11 == 3 + 3
     assert c["heat.halo.bytes"] == b12 + b11
     assert c["heat.kernel.points_computed"] == (
         advance.points_computed(12) + advance.points_computed(11))
     assert c["heat.drive.points_useful"] == 64 * 64 * 23
+
+
+@pytest.mark.parametrize("mesh_shape", MESHES)
+@pytest.mark.parametrize("local_kernel,fits", [("pallas", True),
+                                               ("xla", False)])
+def test_drive_counts_the_lane_cuts(mesh_shape, local_kernel, fits):
+    """``heat.halo.lane_cuts`` counts the exchanges whose column slabs the
+    Pallas strip copy cut: every exchange where each shard rides in the
+    thin-band kernel's aligned 256x128 carry, which fits the tiles, over
+    chunks of 12 and 11 steps (the second ends in a remainder block of
+    3); none where the XLA local kernel carries the 40x40 logical shard,
+    narrower than a 128-lane block."""
+    trace_mod.configure()
+    try:
+        cfg = CFG.with_(mesh_shape=mesh_shape, ntime=23, heartbeat_every=12,
+                        local_kernel=local_kernel)
+        mesh = build_mesh(2, mesh_shape, devices=jax.devices()[:4])
+        solve(cfg, mesh=mesh)
+        c = trace_mod.get_tracer().counts()
+    finally:
+        trace_mod.configure()
+    assert c["heat.halo.exchanges"] == 3 + 3
+    assert c["heat.halo.lane_cuts"] == (c["heat.halo.exchanges"] if fits
+                                        else 0)
+    # the count is the program's: its exchange holds the kernel or not
+    _, (seed, advance, _) = _machinery(cfg)
+    Tp = seed(jnp.zeros((64, 64), jnp.float32))
+    jaxpr = jax.make_jaxpr(lambda T: advance(T, 4))(Tp)
+    assert ("heat_halo_cut" in str(jaxpr)) == fits
 
 
 def _seeded(n):

@@ -365,30 +365,58 @@ def test_build_mesh_cpu_keeps_plain_device_order():
         d.id for d in jax.devices()[:8]]
 
 
+# shards (logical padded extent, carrying array, dtype) beside the square
+# float64 shards: float32 ones whose array fits the TPU tiles, where the
+# column slabs come from the Pallas strip copy, with the high slab inside
+# one 128-lane block ([36, 40) of block 0) and straddling two ([126, 130)
+# over blocks 0 and 1); and one whose 20 rows are no multiple of the
+# sublane tile, where the slabs are cut by an XLA slice
+_CUT = ((48, 44), (48, 128), "float32")
+_STRADDLE = ((40, 134), (48, 256), "float32")
+_ODD_ROWS = ((20, 134), (20, 256), "float32")
+
+
 @pytest.mark.parametrize("periodic", [False, True])
-@pytest.mark.parametrize("mesh_shape,width", [
-    ((4, 2), 1), ((4, 2), 3), ((2, 4), 2), ((2, 2, 2), 2),
+@pytest.mark.parametrize("mesh_shape,width,shard", [
+    pytest.param((4, 2), 1, None, id="mesh_shape0-1"),
+    pytest.param((4, 2), 3, None, id="mesh_shape1-3"),
+    pytest.param((2, 4), 2, None, id="mesh_shape2-2"),
+    pytest.param((2, 2, 2), 2, None, id="mesh_shape3-2"),
+    pytest.param((2, 2), 4, _CUT, id="lane_cut"),
+    pytest.param((2, 2), 4, _STRADDLE, id="lane_cut_straddle"),
+    pytest.param((2, 2), 4, _ODD_ROWS, id="xla_cut_odd_rows"),
 ])
-def test_halo_exchange_indep_bitwise(mesh_shape, width, periodic):
+def test_halo_exchange_indep_bitwise(mesh_shape, width, periodic, shard):
     """halo_exchange_indep must deliver bit-identical ghosts to the
     sequential formulation — including corner/edge regions, whose data
     the indep form forwards by stitching recv slabs instead of re-reading
-    the updated array (parallel/halo.py)."""
+    the updated array (parallel/halo.py) — whether the column slabs are
+    cut by the Pallas strip copy or by an XLA slice, and with the
+    alignment cells beyond a logical extent left as they were."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from jax import shard_map
 
-    from heat_tpu.parallel.halo import halo_exchange, halo_exchange_indep
+    from heat_tpu.parallel.halo import (halo_exchange, halo_exchange_indep,
+                                        lane_cut_plan)
 
     ndim = len(mesh_shape)
     mesh = build_mesh(ndim, mesh_shape)
     rng = np.random.default_rng(17)
     n = 16
     w = width
+    if shard is None:
+        logical = None
+        local = (n + 2 * w,) * ndim
+        dtype = np.float64
+    else:
+        logical, local, dtype = shard
+    cut = lane_cut_plan(local, dtype, w, logical) is not None
+    assert cut == (shard in (_CUT, _STRADDLE))
     # per-shard padded arrays, random everywhere (ghosts hold garbage —
     # both formulations must overwrite every ghost cell they claim to)
-    gshape = tuple(s * (n + 2 * w) for s in mesh_shape)
-    G = rng.normal(size=gshape)
+    gshape = tuple(s * e for s, e in zip(mesh_shape, local))
+    G = rng.normal(size=gshape).astype(dtype)
     spec = P(*mesh.axis_names)
     Gd = jax.device_put(G, NamedSharding(mesh, spec))
 
@@ -396,10 +424,14 @@ def test_halo_exchange_indep_bitwise(mesh_shape, width, periodic):
     for name, fn in (("seq", halo_exchange), ("indep", halo_exchange_indep)):
         def body(local, fn=fn):
             return fn(local, mesh.axis_names, mesh_shape, 2.0,
-                      width=w, periodic=periodic)
+                      width=w, periodic=periodic, logical=logical)
 
-        outs[name] = np.asarray(
-            jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
-                              out_specs=spec))(Gd))
+        # as on every solve path: the interpreted kernel's outputs carry
+        # no mesh-axis variance
+        prog = jax.jit(shard_map(body, mesh=mesh, in_specs=(spec,),
+                                 out_specs=spec, check_vma=False))
+        if name == "indep":
+            assert ("heat_halo_cut" in str(prog.trace(Gd).jaxpr)) == cut
+        outs[name] = np.asarray(prog(Gd))
     np.testing.assert_array_equal(outs["seq"], outs["indep"])
 
